@@ -76,7 +76,7 @@ def decode_occupancy_sweep(
     # the table_* − paged_* gap isolates indirection, not partitioning
     from repro.kernels.swa_decode import _chunk
 
-    page = _chunk(cap)
+    page = _chunk(cap, hkv)
     t_w = cap // page
     flat_k = kc.reshape(slots * t_w, page, hkv, hd)   # row s·t_w + j
     flat_v = vc.reshape(slots * t_w, page, hkv, hd)
@@ -91,18 +91,17 @@ def decode_occupancy_sweep(
     fns = {
         "paged": jax.jit(
             lambda p: ops.swa_decode_attention(
-                q, kc, vc, p, 0, use_kernel=True, paged=True, interpret=True
+                q, kc, vc, p, 0, use_kernel=True, paged=True
             )
         ),
         "unpaged": jax.jit(
             lambda p: ops.swa_decode_attention(
-                q, kc, vc, p, 0, use_kernel=True, paged=False, interpret=True
+                q, kc, vc, p, 0, use_kernel=True, paged=False
             )
         ),
         "table": jax.jit(
             lambda p: ops.swa_decode_attention(
                 q, pool_k, pool_v, p, 0, use_kernel=True, table=table,
-                interpret=True,
             )
         ),
     }
@@ -166,7 +165,7 @@ def suffix_occupancy_sweep(
     def kernel_fn(width):
         return lambda s: suffix_prefill(
             q, k_suf, v_suf, pool_k, pool_v, table, s,
-            prefix_width=width, interpret=True,
+            prefix_width=width,
         )
 
     out = {}
@@ -317,4 +316,7 @@ def run(argv: list[str] | None = None) -> dict:
 if __name__ == "__main__":
     import sys
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(sys.argv[1:])

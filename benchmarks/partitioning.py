@@ -61,4 +61,7 @@ def run() -> dict:
 
 
 if __name__ == "__main__":
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run()

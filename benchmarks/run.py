@@ -20,6 +20,8 @@ import argparse
 import sys
 import time
 
+from repro.utils.compile_cache import enable_compile_cache
+
 MODULES = [
     "table2_comm",
     "table3_convergence",
@@ -38,6 +40,7 @@ def main() -> None:
     ap.add_argument("--only", default="")
     args = ap.parse_args()
     selected = args.only.split(",") if args.only else MODULES
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     failures = []

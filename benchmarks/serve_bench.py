@@ -812,11 +812,13 @@ _SHARDED_PROBE_MARK = "SHARDED_PROBE_JSON "
 
 def bench_sharded(args) -> dict:
     """Run the sharded probe, in-process when this process already holds
-    enough devices (the CI sharded job forces 4 host devices), otherwise
-    by re-execing this module with a forced 2-device host platform —
-    XLA reads ``--xla_force_host_platform_device_count`` once at jaxlib
-    import, so an already-initialized one-device process can never shard
-    itself."""
+    enough devices (the CI sharded job forces 4 host devices; a multi-chip
+    host has them). A one-device CPU host re-execs this module with a
+    forced 2-device host platform — XLA reads
+    ``--xla_force_host_platform_device_count`` once at jaxlib import, so an
+    already-initialized one-device process can never shard itself. On any
+    other platform a single device is an error: the probe never swaps an
+    accelerator for CPU devices."""
     ndev = len(jax.devices())
     shards = args.shards or min(4, ndev)
     if shards >= 2:
@@ -827,6 +829,11 @@ def bench_sharded(args) -> dict:
                 f"--xla_force_host_platform_device_count={shards}"
             )
         return _sharded_probe(args, shards)
+    platform = jax.devices()[0].platform
+    if platform != "cpu":
+        raise RuntimeError(
+            f"sharded probe needs >= 2 {platform} devices, found {ndev}"
+        )
     if args.sharded_probe:
         # we ARE the re-exec (or the CI probe entry) — if the forced
         # device count did not take, recursing would loop forever
@@ -1132,8 +1139,9 @@ def _parser():
                     "virtual time; > 0 runs realtime, honoring arrivals)")
     ap.add_argument("--shards", type=int, default=0,
                     help="model-axis shards for the sharded probe (0 = "
-                    "auto: min(4, visible devices), subprocess fallback "
-                    "on a one-device host)")
+                    "auto: min(4, visible devices); a one-device CPU host "
+                    "re-execs with 2 forced host devices, a one-device "
+                    "accelerator host fails)")
     ap.add_argument("--sharded-probe", action="store_true",
                     help="run ONLY the sharded-vs-unsharded probe and "
                     "print its JSON (the CI sharded job entry point; also "
@@ -1377,4 +1385,7 @@ def run(argv: list[str] | None = None):
 if __name__ == "__main__":
     import sys
 
+    from repro.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     run(sys.argv[1:])

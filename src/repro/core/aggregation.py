@@ -153,22 +153,11 @@ def int8_wire_weighted_average(stacked: Pytree, weights: jax.Array,
         P(),
     )
     out_specs = tree_map(lambda s: P(*s), shard_specs)
-    if hasattr(jax, "shard_map"):  # jax >= 0.7 public API
-        mapped = jax.shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=False,
-        )
-    else:  # jax 0.4.x: experimental module, check_rep spelling
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        mapped = _shard_map(
-            fn,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_rep=False,
-        )
+    mapped = jax.shard_map(
+        fn,
+        mesh=mesh,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        check_vma=False,
+    )
     return mapped(stacked, weights)

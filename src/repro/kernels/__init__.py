@@ -3,8 +3,8 @@
 Layout: one ``<name>.py`` per kernel (pl.pallas_call + BlockSpec),
 ``ops.py`` with the jit'd public wrappers (pytree plumbing + kernel/ref
 dispatch), ``ref.py`` with the pure-jnp oracles every kernel is tested
-against. Kernels target TPU; on this CPU container they are validated in
-``interpret=True`` mode."""
+against, and ``backend.py`` which picks the mode: kernels compile natively
+on the TPU and run in the Pallas interpreter on the CPU backend."""
 from repro.kernels.ops import (
     dp_transmit,
     int8_encode_leaf,

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 ROWS = 8
 BLOCK = 256
 
@@ -33,7 +35,7 @@ def _sq_norm_kernel(x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def sq_norm(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+def sq_norm(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
     """(nb, 256) fp32 → () squared L2 norm."""
     nb, block = x.shape
     assert block == BLOCK and nb % ROWS == 0
@@ -43,7 +45,7 @@ def sq_norm(x: jax.Array, *, interpret: bool = True) -> jax.Array:
         grid=(nb // ROWS,),
         in_specs=[pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
     return out[0, 0]
 
@@ -57,7 +59,7 @@ def _clip_noise_kernel(x_ref, scale_ref, noise_ref, o_ref, *, stddev: float):
 @functools.partial(jax.jit, static_argnames=("stddev", "interpret"))
 def clip_noise(
     x: jax.Array, scale: jax.Array, noise: jax.Array, stddev: float,
-    *, interpret: bool = True,
+    *, interpret: bool | None = None,
 ) -> jax.Array:
     """out = x·scale + stddev·noise. x/noise: (nb, 256); scale: () fp32."""
     nb, block = x.shape
@@ -72,5 +74,5 @@ def clip_noise(
             pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, scale.reshape(1, 1), noise)

@@ -22,7 +22,13 @@ proven in ``kernels/paged_decode.py``: the last grid axis streams
     j in [W, W + S/BK) — the suffix's own k/v blocks, standard causal
                          flash tiling (``kernels/flash_prefill.py``),
 
-carrying the online-softmax state (m, l, acc) in VMEM scratch. ``W`` is a
+carrying the online-softmax state (m, l, acc) of every kv head in VMEM
+scratch. Each grid step takes ALL kv heads: a pool page is (page, Hkv, hd)
+and the TPU lowering only accepts blocks ending in the whole (Hkv, hd), so
+the body swaps each page to head-major in VMEM and runs one batched matmul
+over heads; q and the suffix k/v arrive head-major ((n, Hkv, S·G, hd),
+transposed by the wrapper), with block sizes capped so a block holds at
+most ``ROWS_PER_BLOCK`` (row, head) pairs. ``W`` is a
 STATIC prefix width in pages — the engine buckets ``max(starts)`` up a
 pow2 ladder (``launch/engine.py::bucket_pages``) so compile counts stay
 gated exactly like the (width, length) shape buckets.
@@ -53,7 +59,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.flash_prefill import _block_size
+from repro.kernels.backend import resolve_interpret
+from repro.kernels.flash_prefill import _block_size, causal_mask, to_head_major
+from repro.kernels.swa_decode import ROWS_PER_BLOCK
 
 NEG = -2.0**30
 
@@ -62,7 +70,7 @@ def _suffix_kernel(
     starts_ref, pp_ref, table_ref,
     q_ref, ks_ref, vs_ref, pk_ref, pv_ref,
     *rest,
-    bq: int, bk: int, w: int, page: int, n_total: int, g: int, hd: int,
+    bq: int, bk: int, w: int, page: int, n_total: int, g: int,
     scale: float, deq=None,
 ):
     # rest = ([pks_ref, pvs_ref,] o_ref, m_ref, l_ref, acc_ref) — with
@@ -73,8 +81,8 @@ def _suffix_kernel(
     else:
         o_ref, m_ref, l_ref, acc_ref = rest
     b = pl.program_id(0)
-    i = pl.program_id(2)          # query block
-    j = pl.program_id(3)          # streaming axis: W prefix pages, then
+    i = pl.program_id(1)          # query block
+    j = pl.program_id(2)          # streaming axis: W prefix pages, then
     #                               S/BK suffix blocks
 
     @pl.when(j == 0)
@@ -83,14 +91,20 @@ def _suffix_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _update(s, v):
+    def _update(k, v, mask):
+        # k/v: (Hkv, K, hd) f32 head-major; mask broadcasts to (Hkv, R, K)
+        q = q_ref[0].astype(jnp.float32)                 # (Hkv, BQ·G, hd)
+        s = jnp.einsum(
+            "hrd,hkd->hrk", q, k, preferred_element_type=jnp.float32
+        ) * scale
+        s = jnp.where(mask, s, NEG)
         m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=2, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        l_new = l_prev * alpha + jnp.sum(p, axis=2, keepdims=True)
+        pv = jnp.einsum(
+            "hrk,hkd->hrd", p, v, preferred_element_type=jnp.float32
         )
         acc_new = acc_prev * alpha + pv
         m_ref[...], l_ref[...], acc_ref[...] = m_new, l_new, acc_new
@@ -100,24 +114,21 @@ def _suffix_kernel(
     # re-read the last live page (index-map clamp), never fresh traffic.
     @pl.when((j < w) & (j < pp_ref[b]))
     def _prefix_block():
-        q = q_ref[0, :, 0].astype(jnp.float32).reshape(bq * g, hd)
-        k = pk_ref[0, :, 0]                              # (page, hd)
-        v = pv_ref[0, :, 0]
+        k = pk_ref[0]                                    # (page, Hkv, hd)
+        v = pv_ref[0]
         if deq is not None:
             # in-body dequant, bitwise ``kv_dequant(..., dtype=deq)``
-            k = (k.astype(jnp.float32) * pks_ref[0, :, 0][:, None]).astype(deq)
-            v = (v.astype(jnp.float32) * pvs_ref[0, :, 0][:, None]).astype(deq)
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                        # (BQ·G, page)
+            k = (k.astype(jnp.float32) * pks_ref[0][:, :, None]).astype(deq)
+            v = (v.astype(jnp.float32) * pvs_ref[0][:, :, None]).astype(deq)
         # ring slot c holds global position c (windowless, no wrap); lanes
         # at/after the row's start hold no prefix. Causality is implied:
         # every query position is >= starts[b] > any live prefix lane.
-        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        s = jnp.where(kpos < starts_ref[b], s, NEG)
-        _update(s, v)
+        kpos = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, 1, page), 2)
+        _update(
+            jnp.swapaxes(k.astype(jnp.float32), 0, 1),
+            jnp.swapaxes(v.astype(jnp.float32), 0, 1),
+            kpos < starts_ref[b],
+        )
 
     # ---- suffix phase: standard causal flash tiling in LOCAL suffix
     # coordinates (absolute = starts[b] + local on both sides, so the
@@ -129,21 +140,16 @@ def _suffix_kernel(
 
     @pl.when((j >= w) & (k_lo <= q_hi))
     def _suffix_block():
-        q = q_ref[0, :, 0].astype(jnp.float32).reshape(bq * g, hd)
-        k = ks_ref[0, :, 0].astype(jnp.float32)          # (BK, hd)
-        v = vs_ref[0, :, 0].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                        # (BQ·G, BK)
-        qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, g, bk), 0)
-        kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, g, bk), 2)
-        s = jnp.where((qpos >= kpos).reshape(bq * g, bk), s, NEG)
-        _update(s, v)
+        _update(
+            ks_ref[0].astype(jnp.float32),               # (Hkv, BK, hd)
+            vs_ref[0].astype(jnp.float32),
+            causal_mask(q_lo, k_lo, (1, bq * g, bk), g),
+        )
 
     @pl.when(j == n_total - 1)
     def _finish():
         out = acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, :, 0] = out.reshape(bq, g, hd).astype(o_ref.dtype)
+        o_ref[0] = out.astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -165,7 +171,7 @@ def suffix_prefill(
     pool_v_scale: jax.Array | None = None,
     bq: int = 256,
     bk: int = 256,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Returns (n, S, Hkv, G, hd) attention output, fp32-accumulated."""
     n, s, hkv, g, hd = q.shape
@@ -175,8 +181,9 @@ def suffix_prefill(
     assert w >= 1, f"prefix_width must be >= 1, got {prefix_width}"
     quant = pool_k_scale is not None
     assert quant == (pool_v_scale is not None), "need both or neither scale"
-    bq = _block_size(s, bq)
-    bk = _block_size(s, bk)
+    # every step holds all kv heads: cap the (row, head) pairs per block
+    bq = _block_size(s, min(bq, max(8, ROWS_PER_BLOCK // (hkv * g))))
+    bk = _block_size(s, min(bk, max(8, ROWS_PER_BLOCK // hkv)))
     scale = hd**-0.5
     n_total = w + s // bk
 
@@ -188,58 +195,64 @@ def suffix_prefill(
 
     kernel = functools.partial(
         _suffix_kernel, bq=bq, bk=bk, w=w, page=page, n_total=n_total,
-        g=g, hd=hd, scale=scale, deq=q.dtype if quant else None,
+        g=g, scale=scale, deq=q.dtype if quant else None,
     )
 
-    def q_map(b, h, i, j, *_):
-        return (b, i, h, 0, 0)
+    def q_map(b, i, j, *_):
+        return (b, 0, i, 0)
 
-    def suf_map(b, h, i, j, *_):
+    def suf_map(b, i, j, *_):
         # prefix-phase steps clamp to suffix block 0: already resident,
         # no fresh DMA (the body never touches it before j reaches w)
-        return (b, jnp.maximum(j - w, 0), h, 0)
+        return (b, 0, jnp.maximum(j - w, 0), 0)
 
-    def pool_map(b, h, i, j, starts_ref, pp_ref, table_ref):
+    def live_page(b, j, pp_ref, table_ref):
         # page-table indirection with the paged-decode clamp: suffix-phase
         # steps and dead prefix pages re-read the last live page (clamp
         # BEFORE the table lookup so an unallocated entry — scratch page 0
         # by convention — is never the target of a live-block DMA)
         jp = jnp.minimum(jnp.minimum(j, w - 1), pp_ref[b] - 1)
-        return (table_ref[b, jnp.maximum(jp, 0)], 0, h, 0)
+        return table_ref[b, jnp.maximum(jp, 0)]
 
-    def pool_scale_map(b, h, i, j, starts_ref, pp_ref, table_ref):
-        jp = jnp.minimum(jnp.minimum(j, w - 1), pp_ref[b] - 1)
-        return (table_ref[b, jnp.maximum(jp, 0)], 0, h)
+    def pool_map(b, i, j, starts_ref, pp_ref, table_ref):
+        return (live_page(b, j, pp_ref, table_ref), 0, 0, 0)
+
+    def pool_scale_map(b, i, j, starts_ref, pp_ref, table_ref):
+        return (live_page(b, j, pp_ref, table_ref), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, bq, 1, g, hd), q_map),
-        pl.BlockSpec((1, bk, 1, hd), suf_map),
-        pl.BlockSpec((1, bk, 1, hd), suf_map),
-        pl.BlockSpec((1, page, 1, hd), pool_map),
-        pl.BlockSpec((1, page, 1, hd), pool_map),
+        pl.BlockSpec((1, hkv, bq * g, hd), q_map),
+        pl.BlockSpec((1, hkv, bk, hd), suf_map),
+        pl.BlockSpec((1, hkv, bk, hd), suf_map),
+        pl.BlockSpec((1, page, hkv, hd), pool_map),
+        pl.BlockSpec((1, page, hkv, hd), pool_map),
     ]
-    inputs = [q, k_suf, v_suf, pool_k, pool_v]
+    inputs = [
+        to_head_major(q), to_head_major(k_suf), to_head_major(v_suf),
+        pool_k, pool_v,
+    ]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, page, 1), pool_scale_map),
-            pl.BlockSpec((1, page, 1), pool_scale_map),
+            pl.BlockSpec((1, page, hkv), pool_scale_map),
+            pl.BlockSpec((1, page, hkv), pool_scale_map),
         ]
         inputs += [pool_k_scale, pool_v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(n, hkv, s // bq, n_total),
+        grid=(n, s // bq, n_total),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, bq, 1, g, hd), q_map),
+        out_specs=pl.BlockSpec((1, hkv, bq * g, hd), q_map),
         scratch_shapes=[
-            pltpu.VMEM((bq * g, 1), jnp.float32),
-            pltpu.VMEM((bq * g, 1), jnp.float32),
-            pltpu.VMEM((bq * g, hd), jnp.float32),
+            pltpu.VMEM((hkv, bq * g, 1), jnp.float32),
+            pltpu.VMEM((hkv, bq * g, 1), jnp.float32),
+            pltpu.VMEM((hkv, bq * g, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
-        out_shape=jax.ShapeDtypeStruct((n, s, hkv, g, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((n, hkv, s * g, hd), q.dtype),
         grid_spec=grid_spec,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(starts, pp, table, *inputs)
+    return jnp.moveaxis(out.reshape(n, hkv, s, g, hd), 1, 2)

@@ -2,9 +2,9 @@
 
 The wrappers own the layout plumbing the kernels don't: flattening pytrees
 into (rows, 256) tiles (with padding), restoring shapes, and dispatching
-kernel vs. pure-jnp reference (``use_kernel=False`` is the CPU production
-path; kernels run interpret=True on CPU for validation and compile natively
-on TPU)."""
+kernel vs. pure-jnp reference. ``interpret=None`` (the default) lets
+``kernels/backend.py`` choose: the Pallas interpreter on the CPU backend, a
+native compile on the TPU."""
 from __future__ import annotations
 
 import functools
@@ -42,7 +42,8 @@ def _from_tiles(t: jax.Array, n: int, shape, dtype) -> jax.Array:
 
 # ----------------------------------------------------------- top-k sparsify
 def topk_sparsify_leaf(
-    x: jax.Array, ratio: float, *, use_kernel: bool = False, interpret: bool = True
+    x: jax.Array, ratio: float, *, use_kernel: bool = False,
+    interpret: bool | None = None,
 ) -> jax.Array:
     tiles, n = _to_tiles(x)
     k = max(1, int(round(ratio * BLOCK)))
@@ -55,7 +56,7 @@ def topk_sparsify_leaf(
 
 # ------------------------------------------------------------ int8 channel
 def int8_roundtrip_leaf(
-    x: jax.Array, *, use_kernel: bool = False, interpret: bool = True
+    x: jax.Array, *, use_kernel: bool = False, interpret: bool | None = None
 ) -> jax.Array:
     tiles, n = _to_tiles(x)
     if use_kernel:
@@ -65,7 +66,9 @@ def int8_roundtrip_leaf(
     return _from_tiles(out, n, x.shape, x.dtype)
 
 
-def int8_encode_leaf(x: jax.Array, *, use_kernel: bool = False, interpret: bool = True):
+def int8_encode_leaf(
+    x: jax.Array, *, use_kernel: bool = False, interpret: bool | None = None
+):
     tiles, n = _to_tiles(x)
     if use_kernel:
         return _quant.int8_encode(tiles, interpret=interpret) + (n,)
@@ -74,7 +77,7 @@ def int8_encode_leaf(x: jax.Array, *, use_kernel: bool = False, interpret: bool 
 
 # ------------------------------------------------------------------ DP clip
 def tree_sq_norm(
-    tree: Pytree, *, use_kernel: bool = False, interpret: bool = True
+    tree: Pytree, *, use_kernel: bool = False, interpret: bool | None = None
 ) -> jax.Array:
     total = jnp.zeros((), jnp.float32)
     for leaf in jax.tree_util.tree_leaves(tree):
@@ -93,7 +96,7 @@ def dp_transmit(
     stddev: float,
     *,
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> Pytree:
     """Fused DP channel: clip the pytree to clip_norm, add N(0, stddev²)."""
     norm = jnp.sqrt(tree_sq_norm(tree, use_kernel=use_kernel, interpret=interpret))
@@ -125,7 +128,7 @@ def swa_decode_attention(
     table: jax.Array | None = None,
     k_scale: jax.Array | None = None,
     v_scale: jax.Array | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """(B, Hkv, G, hd) x ring cache (B, C, Hkv, hd) → (B, Hkv, G, hd).
 
@@ -172,7 +175,7 @@ def swa_decode_attention(
 # -------------------------------------------------------- flash prefill attn
 def flash_prefill_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *, causal: bool = True,
-    window: int = 0, use_kernel: bool = False, interpret: bool = True,
+    window: int = 0, use_kernel: bool = False, interpret: bool | None = None,
 ) -> jax.Array:
     """Causal GQA flash attention for training/prefill (see
     kernels/flash_prefill.py). q: (B,S,Hkv,G,hd); k/v: (B,T,Hkv,hd)."""
@@ -199,7 +202,7 @@ def suffix_prefill_attention(
     pool_k_scale: jax.Array | None = None,
     pool_v_scale: jax.Array | None = None,
     use_kernel: bool = False,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Suffix prefill over a cached prefix held in a shared page pool (see
     kernels/flash_suffix_prefill.py). q: (n,S,Hkv,G,hd) roped at absolute

@@ -10,7 +10,8 @@ into pages of ``page`` slots, the per-row number of LIVE pages
     live_pages[b] = ceil(min(pos[b] + 1, C) / page)
 
 is scalar-prefetched (``pltpu.PrefetchScalarGridSpec``), and the grid is
-(B, Hkv, C/page) where dead pages are skipped two ways:
+(B, C/page) — each step takes one page of ALL kv heads, as in
+``swa_decode`` — where dead pages are skipped two ways:
 
 * the k/v index map clamps the page index to ``live_pages[b] - 1``, so a
   dead page issues NO new DMA (it re-reads the already-resident last live
@@ -38,7 +39,7 @@ page ``table[b, j]``, so a slot's pages may sit ANYWHERE in the pool
 (vLLM-PagedAttention layout). The table rows are scalar-prefetched along
 with ``pos``/``live_pages`` and drive the k/v DMA index map directly:
 
-    kv_block(b, h, j) = pool[table[b, min(j, live_pages[b]-1)]]
+    kv_block(b, j) = pool[table[b, min(j, live_pages[b]-1)]]
 
 Everything else — the ring-position validity mask over LOGICAL slot
 indices ``j·page + i`` with capacity C = T·page, the live-page gating, the
@@ -51,7 +52,8 @@ page size differs from its auto chunk — online softmax reassociates).
 int8 pool mode (``k_scale``/``v_scale`` passed with ``table``): the pool
 pages are int8 with one f32 scale per page slot per kv-head, shape
 (P, page, Hkv), riding the SAME scalar-prefetched table indirection as the
-pages themselves. The body dequantizes each block to the fp pool dtype
+pages themselves (one (page, Hkv) scale block per step). The body
+dequantizes each block to the fp pool dtype
 (``kv_quant``'s row scheme inverted: ``q·s`` in f32, cast) before the
 unchanged online-softmax math, so the int8 kernel is bitwise equal to the
 fp kernel run over the jnp-dequantized pool — the pin the tests use; the
@@ -66,9 +68,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.swa_decode import _chunk
-
-NEG = -2.0**30
+from repro.kernels.backend import resolve_interpret
+from repro.kernels.swa_decode import (
+    _attend_block,
+    _chunk,
+    _init_state,
+    _ring_valid,
+    _state_scratch,
+    _write_out,
+)
 
 
 def _paged_kernel(
@@ -90,54 +98,31 @@ def _paged_kernel(
     else:
         q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref = refs[-7:]
     b = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     n_pages = cap // page
 
     @pl.when(j == 0)
     def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        _init_state(m_ref, l_ref, acc_ref)
 
     @pl.when(j < pages_ref[b])
     def _live_page():
-        pos = pos_ref[b]
-        q = q_ref[0, 0].astype(jnp.float32)            # (G, hd)
-        k = k_ref[0, :, 0]                             # (page, hd)
-        v = v_ref[0, :, 0]
+        k = k_ref[0]                                   # (page, Hkv, hd)
+        v = v_ref[0]
         if deq is not None:
-            k = (k.astype(jnp.float32) * ks_ref[0, :, 0][:, None]).astype(deq)
-            v = (v.astype(jnp.float32) * vs_ref[0, :, 0][:, None]).astype(deq)
-        k = k.astype(jnp.float32)
-        v = v.astype(jnp.float32)
-
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                      # (G, page)
-
-        slots = j * page + jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
-        slot_w = pos % cap
-        gpos = pos - (slot_w - slots) % cap
-        lo = jnp.maximum(pos - (window - 1), 0) if window > 0 else 0
-        valid = (gpos >= lo) & (gpos <= pos)           # (1, page)
-        s = jnp.where(valid, s, NEG)
-
-        m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)                          # (G, page)
-        alpha = jnp.exp(m_prev - m_new)                 # (G, 1)
-        l_new = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )                                               # (G, hd)
-        acc_new = acc_prev * alpha + pv
-        m_ref[...], l_ref[...], acc_ref[...] = m_new, l_new, acc_new
+            k = (k.astype(jnp.float32) * ks_ref[0][:, :, None]).astype(deq)
+            v = (v.astype(jnp.float32) * vs_ref[0][:, :, None]).astype(deq)
+        _attend_block(
+            q_ref[0].astype(jnp.float32),
+            k.astype(jnp.float32),
+            v.astype(jnp.float32),
+            _ring_valid(pos_ref[b], j * page, page, cap, window),
+            m_ref, l_ref, acc_ref, scale,
+        )
 
     @pl.when(j == n_pages - 1)
     def _finish():
-        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
-            o_ref.dtype
-        )
+        _write_out(o_ref, l_ref, acc_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "page", "interpret"))
@@ -148,13 +133,14 @@ def paged_decode(
     pos: jax.Array,        # () or (B,) i32 — tokens already cached per row
     window: int = 0,
     *,
-    page: int = 0,         # 0 = auto (largest of 512/256/128/64 dividing C)
+    page: int = 0,         # 0 = auto (swa_decode's chunk for this cache)
     table: jax.Array | None = None,  # (B, T) i32 page table → pool mode
     k_scale: jax.Array | None = None,  # (P, page, Hkv) f32 — int8 pool mode
     v_scale: jax.Array | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     b, hkv, g, hd = q.shape
+    interpret = resolve_interpret(interpret)
     if table is not None:
         return _table_decode(
             q, k_cache, v_cache, pos, table, window=window,
@@ -164,7 +150,7 @@ def paged_decode(
         "int8 pool scales require page-table mode"
     )
     cap = k_cache.shape[1]
-    pg = page or _chunk(cap)
+    pg = page or _chunk(cap, hkv)
     assert cap % pg == 0, f"cap {cap} not divisible by page {pg}"
     scale = hd**-0.5
     n_pages = cap // pg
@@ -178,24 +164,20 @@ def paged_decode(
         _paged_kernel, page=pg, cap=cap, window=window, scale=scale
     )
 
-    def kv_map(b_, h, j, pos_ref, pages_ref):
+    def kv_map(b_, j, pos_ref, pages_ref):
         # dead pages re-read the last live page: no fresh DMA
-        return (b_, jnp.minimum(j, pages_ref[b_] - 1), h, 0)
+        return (b_, jnp.minimum(j, pages_ref[b_] - 1), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, hkv, n_pages),
+        grid=(b, n_pages),
         in_specs=[
-            pl.BlockSpec((1, 1, g, hd), lambda b_, h, j, *_: (b_, h, 0, 0)),
-            pl.BlockSpec((1, pg, 1, hd), kv_map),
-            pl.BlockSpec((1, pg, 1, hd), kv_map),
+            pl.BlockSpec((1, hkv, g, hd), lambda b_, j, *_: (b_, 0, 0, 0)),
+            pl.BlockSpec((1, pg, hkv, hd), kv_map),
+            pl.BlockSpec((1, pg, hkv, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b_, h, j, *_: (b_, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, hkv, g, hd), lambda b_, j, *_: (b_, 0, 0, 0)),
+        scratch_shapes=_state_scratch(hkv, g, hd),
     )
     return pl.pallas_call(
         kernel,
@@ -215,14 +197,14 @@ def _table_decode(
     #                        live span are never dereferenced (index map
     #                        clamps to the last live page first)
     *,
-    window: int = 0,
-    k_scale: jax.Array | None = None,  # (P, page, Hkv) f32 per-slot-per-head
-    v_scale: jax.Array | None = None,  # scales → int8 pool mode (dequant
+    window: int,
+    k_scale: jax.Array | None,  # (P, page, Hkv) f32 per-slot-per-head
+    v_scale: jax.Array | None,  # scales → int8 pool mode (dequant
     #                        in-body to q.dtype, the fp pool dtype)
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, hkv, g, hd = q.shape
-    p_total, pg = k_pool.shape[0], k_pool.shape[1]
+    pg = k_pool.shape[1]
     t_w = table.shape[1]
     cap = t_w * pg         # logical ring capacity per row
     scale = hd**-0.5
@@ -239,41 +221,37 @@ def _table_decode(
         deq=q.dtype if quant else None,
     )
 
-    def kv_map(b_, h, j, pos_ref, pages_ref, table_ref):
+    def kv_map(b_, j, pos_ref, pages_ref, table_ref):
         # page-table indirection: logical page j of row b_ lives wherever
         # the slot's table row says; dead logical pages re-read the last
         # live one (clamp BEFORE the table lookup, so an unallocated table
         # entry — by convention 0, the reserved scratch page — is never
         # the target of a fresh DMA for a live computation)
-        return (table_ref[b_, jnp.minimum(j, pages_ref[b_] - 1)], 0, h, 0)
+        return (table_ref[b_, jnp.minimum(j, pages_ref[b_] - 1)], 0, 0, 0)
 
-    def scale_map(b_, h, j, pos_ref, pages_ref, table_ref):
+    def scale_map(b_, j, pos_ref, pages_ref, table_ref):
         # scales ride the same table indirection as their pages
-        return (table_ref[b_, jnp.minimum(j, pages_ref[b_] - 1)], 0, h)
+        return (table_ref[b_, jnp.minimum(j, pages_ref[b_] - 1)], 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, 1, g, hd), lambda b_, h, j, *_: (b_, h, 0, 0)),
-        pl.BlockSpec((1, pg, 1, hd), kv_map),
-        pl.BlockSpec((1, pg, 1, hd), kv_map),
+        pl.BlockSpec((1, hkv, g, hd), lambda b_, j, *_: (b_, 0, 0, 0)),
+        pl.BlockSpec((1, pg, hkv, hd), kv_map),
+        pl.BlockSpec((1, pg, hkv, hd), kv_map),
     ]
     inputs = [q, k_pool, v_pool]
     if quant:
         in_specs += [
-            pl.BlockSpec((1, pg, 1), scale_map),
-            pl.BlockSpec((1, pg, 1), scale_map),
+            pl.BlockSpec((1, pg, hkv), scale_map),
+            pl.BlockSpec((1, pg, hkv), scale_map),
         ]
         inputs += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, t_w),
+        grid=(b, t_w),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, g, hd), lambda b_, h, j, *_: (b_, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, hd), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, hkv, g, hd), lambda b_, j, *_: (b_, 0, 0, 0)),
+        scratch_shapes=_state_scratch(hkv, g, hd),
     )
     return pl.pallas_call(
         kernel,

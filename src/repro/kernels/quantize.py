@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 ROWS = 8
 BLOCK = 256
 EPS = 1e-12
@@ -71,7 +73,7 @@ def _roundtrip_kernel(x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def int8_encode(x: jax.Array, *, interpret: bool = True):
+def int8_encode(x: jax.Array, *, interpret: bool | None = None):
     nb, block = x.shape
     assert block == BLOCK
     xp = _pad_rows(x)
@@ -87,13 +89,15 @@ def int8_encode(x: jax.Array, *, interpret: bool = True):
             pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
             pl.BlockSpec((ROWS, 1), lambda i: (i, 0)),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xp)
     return q[:nb], s[:nb]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def int8_roundtrip(x: jax.Array, *, interpret: bool = True) -> jax.Array:
+def int8_roundtrip(
+    x: jax.Array, *, interpret: bool | None = None
+) -> jax.Array:
     nb, block = x.shape
     assert block == BLOCK
     xp = _pad_rows(x)
@@ -103,6 +107,6 @@ def int8_roundtrip(x: jax.Array, *, interpret: bool = True) -> jax.Array:
         grid=(xp.shape[0] // ROWS,),
         in_specs=[pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xp)
     return out[:nb]

@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 ROWS = 8
 BLOCK = 256
 
@@ -47,7 +49,9 @@ def _topk_kernel(x_ref, o_ref, *, k: int):
 
 
 @functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def topk_sparsify(x: jax.Array, k: int, *, interpret: bool = True) -> jax.Array:
+def topk_sparsify(
+    x: jax.Array, k: int, *, interpret: bool | None = None
+) -> jax.Array:
     """x: (nb, 256) fp32 → same shape with sub-threshold entries zeroed.
 
     nb must be a multiple of 8 (pad upstream)."""
@@ -60,5 +64,5 @@ def topk_sparsify(x: jax.Array, k: int, *, interpret: bool = True) -> jax.Array:
         grid=(nb // ROWS,),
         in_specs=[pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)

@@ -25,7 +25,6 @@ import dataclasses
 import json
 import time
 import traceback
-from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -149,30 +148,13 @@ def lower_federated_train(cfg, shape, mesh, microbatches, fed_cfg=None):
     train_cfg = TrainConfig(seq_len=shape.seq_len, global_batch=shape.global_batch)
     params_only = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     p_pspec = meshlib.params_pspec_tree(params_only, cfg, mesh)
-    pod_p = meshlib.params_pspec_tree(params_only, cfg, mesh, prefix=("pod",))
     trainer, fed_step = make_federated_step(
         model, fed_cfg, train_cfg, microbatches,
         grad_shardings=_ns(mesh, p_pspec), mesh=mesh,
     )
 
     state_s = jax.eval_shape(trainer.init_state, jax.random.PRNGKey(0))
-
-    state_pspec: dict[str, Any] = {
-        "clouds": {
-            "params": pod_p,
-            "opt": {"m": pod_p, "v": pod_p, "count": P("pod")},
-        },
-        "global": {
-            "params": p_pspec,
-            "outer": jax.tree_util.tree_map(lambda _: P(), state_s["global"]["outer"]),
-        },
-        "sample_counts": P("pod"),
-        "loss_accum": P("pod"),
-        "step": P(),
-        "rng": P(),
-    }
-    if "ef" in state_s:
-        state_pspec["ef"] = pod_p
+    state_pspec = meshlib.federated_state_pspecs(state_s, cfg, mesh)
     batch_s = speclib.train_batch_specs(cfg, shape, n_pods=n_pods)
     b_pspec = meshlib.batch_pspec(batch_s, mesh, pod_stacked=True, pure_dp=cfg.pure_dp)
 

@@ -76,7 +76,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from repro.configs import get_config, get_smoke_config
@@ -834,18 +833,18 @@ class ServeEngine:
             specs, every other operand replicated, (cache, logits) out.
             Replication of the logits is real, not asserted-away — each
             shard all-gathers the attention heads and runs the identical
-            replicated tail (``check_rep=False`` only because the rep
-            checker has no rule for the interpret-mode Pallas calls).
+            replicated tail (``check_vma=False`` only because the
+            varying-axes checker has no rule for the Pallas calls).
             ``mesh=None`` returns fn untouched, so the single-device trace
             stays bitwise the pre-mesh one."""
             if mesh is None:
                 return fn
             rep = PartitionSpec()
-            return shard_map(
+            return jax.shard_map(
                 fn, mesh=mesh,
                 in_specs=(self._pspecs, self._cspecs) + (rep,) * n_extra,
                 out_specs=(self._cspecs, rep),
-                check_rep=False,
+                check_vma=False,
             )
 
         def _decode_fn(p, c, t):
@@ -2618,9 +2617,14 @@ def serve_continuous(
     seed: int = 0,
     stagger: float = 0.0,
     max_wall_s: float = 0.0,
+    requests: list[Request] | None = None,
     log_fn=print,
 ) -> dict:
     """Build a model + engine, serve a synthetic trace, report throughput.
+
+    ``requests`` replaces the synthetic trace (``n_requests`` prompts of
+    ``prompt_len`` tokens, ``gen_tokens`` each) with the caller's own; the
+    engine is then sized and warmed for their lengths.
 
     The serving CLI defaults to the PAGED cache (``--no-paged-cache``
     restores per-slot contiguous rings) — output is token-identical either
@@ -2642,11 +2646,17 @@ def serve_continuous(
         # target's own arch gives identical params, the ~100% acceptance
         # probe configuration serve_bench --spec-probe exploits
         draft_params = draft_model.init(jax.random.PRNGKey(seed))
+    if requests is None:
+        requests = make_requests(
+            cfg, n_requests=n_requests, prompt_len=prompt_len,
+            gen_tokens=gen_tokens, seed=seed, stagger=stagger,
+        )
+    prompt_lens = sorted({len(r.prompt) for r in requests})
     engine = ServeEngine(
         model,
         params,
         num_slots=num_slots,
-        max_seq=prompt_len + gen_tokens,
+        max_seq=max(len(r.prompt) + r.max_new_tokens for r in requests),
         window=window,
         use_kernel=use_kernel,
         prefill=prefill,
@@ -2671,12 +2681,8 @@ def serve_continuous(
         seed=seed,
         max_wall_s=max_wall_s,
     )
-    reqs = make_requests(
-        cfg, n_requests=n_requests, prompt_len=prompt_len,
-        gen_tokens=gen_tokens, seed=seed, stagger=stagger,
-    )
     if sampling is not None and not sampling.is_greedy:
-        for r in reqs:
+        for r in requests:
             # distinct stream per request even under a shared CLI seed
             r.sampling = dataclasses.replace(
                 sampling,
@@ -2684,18 +2690,18 @@ def serve_continuous(
             )
     # trace prefill + decode outside the measured window so the reported
     # throughput/latency are steady-state, not jit compilation
-    engine.warm([prompt_len], gen_tokens=min(2, gen_tokens), sampling=sampling)
+    engine.warm(prompt_lens, gen_tokens=min(2, gen_tokens), sampling=sampling)
     t0 = time.time()
-    outs = engine.run(reqs, realtime=stagger > 0)
+    outs = engine.run(requests, realtime=stagger > 0)
     wall = time.time() - t0
     total = sum(len(o.tokens) for o in outs)
     lat = [o.latency for o in outs] or [0.0]
     result = {
         "arch": cfg.name,
         "num_slots": num_slots,
-        "n_requests": n_requests,
-        "prompt_len": prompt_len,
-        "gen_tokens": gen_tokens,
+        "n_requests": len(requests),
+        "prompt_len": prompt_lens[-1],
+        "gen_tokens": max(r.max_new_tokens for r in requests),
         "window": window,
         "use_kernel": use_kernel,
         "prefill": prefill,
@@ -2756,7 +2762,8 @@ def serve_continuous(
                 f"{ps['spec_dispatches_per_token']:.2f} dispatch/tok"
             )
     log_fn(
-        f"{cfg.name}: {n_requests} reqs × {gen_tokens} tok over "
+        f"{cfg.name}: {result['n_requests']} reqs × "
+        f"{result['gen_tokens']} tok over "
         f"{num_slots} slots in {engine.steps} steps "
         f"+ {engine.prefill_dispatches} prefill dispatches, {wall:.2f}s "
         f"({result['tokens_per_second']:.1f} tok/s, "

@@ -32,10 +32,15 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
 
 
 def make_sim_mesh(n_clouds: int = 1) -> Mesh:
-    """CPU simulation mesh: pod axis only (requires host device override)."""
+    """One cloud per device on a ``pod``-only mesh (on CPU this needs the
+    host device override). The axis is Auto: the federated step shards it
+    through ``vmap(spmd_axis_name="pod")`` and lets XLA propagate."""
     n = len(jax.devices())
-    assert n >= n_clouds, f"need {n_clouds} devices, have {n}"
-    return jax.make_mesh((n_clouds,), ("pod",))
+    if n < n_clouds:
+        raise ValueError(f"need {n_clouds} devices, have {n}")
+    return jax.make_mesh(
+        (n_clouds,), ("pod",), axis_types=(jax.sharding.AxisType.Auto,)
+    )
 
 
 def axis_size(mesh, name: str) -> int:
@@ -139,6 +144,35 @@ def shardings_from_pspecs(pspecs: Pytree, mesh: Mesh) -> Pytree:
 
 
 # ------------------------------------------------------------ non-param state
+def federated_state_pspecs(state_shapes: Pytree, cfg: ModelConfig, mesh: Mesh) -> Pytree:
+    """Specs for ``FederatedTrainer`` state on a mesh with a ``pod`` axis:
+    every per-cloud leaf (params, AdamW moments, error feedback, counts)
+    splits its leading cloud axis over ``pod``; the global model follows
+    the intra-pod parameter rules; step and rng replicate."""
+    params = state_shapes["global"]["params"]
+    p_pspec = params_pspec_tree(params, cfg, mesh)
+    pod_p = params_pspec_tree(params, cfg, mesh, prefix=("pod",))
+    specs: dict[str, Any] = {
+        "clouds": {
+            "params": pod_p,
+            "opt": {"m": pod_p, "v": pod_p, "count": P("pod")},
+        },
+        "global": {
+            "params": p_pspec,
+            "outer": jax.tree_util.tree_map(
+                lambda _: P(), state_shapes["global"]["outer"]
+            ),
+        },
+        "sample_counts": P("pod"),
+        "loss_accum": P("pod"),
+        "step": P(),
+        "rng": P(),
+    }
+    if "ef" in state_shapes:
+        specs["ef"] = pod_p
+    return specs
+
+
 def opt_pspec_tree(opt_shapes: Pytree, param_pspecs: Pytree, mesh: Mesh) -> Pytree:
     """AdamW m/v inherit the parameter sharding (ZeRO: fsdp covers them)."""
     return {

@@ -96,6 +96,7 @@ from repro.configs import get_config, get_smoke_config
 from repro.data import SyntheticCorpus
 from repro.models import build_model
 from repro.models import attention
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def serve_batch(
@@ -179,6 +180,7 @@ def serve_batch(
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--smoke", action="store_true", default=True)

@@ -23,6 +23,7 @@ from repro.core.federated import FederatedTrainer
 from repro.core.scheduler import CloudSpec, events_to_round_masks, simulate_async_schedule
 from repro.data import SyntheticCorpus, dirichlet_mixtures, federated_batch
 from repro.models import build_model
+from repro.utils.compile_cache import enable_compile_cache
 from repro.utils.tree import tree_count_params
 
 
@@ -122,6 +123,7 @@ def run_training(
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="stablelm-1.6b")
     ap.add_argument("--smoke", action="store_true", default=True)

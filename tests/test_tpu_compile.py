@@ -1,0 +1,152 @@
+"""The serving attention kernels compile natively for a TPU v5e.
+
+Interpret mode (the rest of the suite) accepts block shapes the TPU
+lowering refuses, so these tests compile every serving kernel with
+``interpret=False`` for one device of a DESCRIBED ``v5e:2x2`` topology, at
+stablelm-1.6b widths (32 kv heads of head_dim 64, bf16, page 16). Nothing
+runs: a compile that passes here proves the Mosaic lowering and the
+compiler accept the kernel, and each test checks the kernel is really in
+the program (``tpu_custom_call``).
+
+The topology is described inside a module-scoped fixture (never at import:
+only one process at a time may load the TPU library, and test workers
+import every test file), and the persistent compilation cache is off
+around these compiles — an entry written for a described chip cannot be
+read back on a host without one."""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.flash_prefill import flash_prefill
+from repro.kernels.flash_suffix_prefill import suffix_prefill
+from repro.kernels.paged_decode import paged_decode
+from repro.kernels.swa_decode import swa_decode
+
+CFG = get_config("stablelm-1.6b")
+HKV = CFG.n_kv_heads
+G = CFG.n_heads // CFG.n_kv_heads
+HD = CFG.resolved_head_dim
+PAGE = 16
+N_PAGES = 1025            # 16k-token pool + scratch page 0
+SLOTS = 4
+TABLE_W = 64              # 1k logical tokens per slot
+PROMPT = 1024
+SUFFIX = 256
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure means "cannot describe"
+        jax.config.update("jax_enable_compilation_cache", prev)
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+def _shape(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compiled_text(fn, *args) -> str:
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _decode_args(sh, kv_dtype):
+    q = _shape(sh, (SLOTS, HKV, G, HD), CFG.dtype)
+    pool = _shape(sh, (N_PAGES, PAGE, HKV, HD), kv_dtype)
+    pos = _shape(sh, (SLOTS,), jnp.int32)
+    table = _shape(sh, (SLOTS, TABLE_W), jnp.int32)
+    scale = _shape(sh, (N_PAGES, PAGE, HKV), jnp.float32)
+    return q, pool, pos, table, scale
+
+
+def _suffix_args(sh, kv_dtype):
+    q = _shape(sh, (SLOTS, SUFFIX, HKV, G, HD), CFG.dtype)
+    kv = _shape(sh, (SLOTS, SUFFIX, HKV, HD), CFG.dtype)
+    pool = _shape(sh, (N_PAGES, PAGE, HKV, HD), kv_dtype)
+    table = _shape(sh, (SLOTS, TABLE_W), jnp.int32)
+    starts = _shape(sh, (SLOTS,), jnp.int32)
+    scale = _shape(sh, (N_PAGES, PAGE, HKV), jnp.float32)
+    return q, kv, pool, table, starts, scale
+
+
+def test_paged_decode_table_fp(one_chip):
+    q, pool, pos, table, _ = _decode_args(one_chip, CFG.dtype)
+    fn = functools.partial(paged_decode, interpret=False)
+    text = _compiled_text(
+        lambda q, k, v, p, t: fn(q, k, v, p, table=t), q, pool, pool, pos, table
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_table_int8(one_chip):
+    q, pool, pos, table, scale = _decode_args(one_chip, jnp.int8)
+    fn = functools.partial(paged_decode, interpret=False)
+    text = _compiled_text(
+        lambda q, k, v, p, t, ks, vs: fn(
+            q, k, v, p, table=t, k_scale=ks, v_scale=vs
+        ),
+        q, pool, pool, pos, table, scale, scale,
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_paged_decode_ring(one_chip):
+    q = _shape(one_chip, (SLOTS, HKV, G, HD), CFG.dtype)
+    ring = _shape(one_chip, (SLOTS, PROMPT, HKV, HD), CFG.dtype)
+    pos = _shape(one_chip, (SLOTS,), jnp.int32)
+    text = _compiled_text(
+        functools.partial(paged_decode, interpret=False), q, ring, ring, pos
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_swa_decode(one_chip):
+    q = _shape(one_chip, (SLOTS, HKV, G, HD), CFG.dtype)
+    ring = _shape(one_chip, (SLOTS, PROMPT, HKV, HD), CFG.dtype)
+    pos = _shape(one_chip, (SLOTS,), jnp.int32)
+    text = _compiled_text(
+        functools.partial(swa_decode, interpret=False, window=256),
+        q, ring, ring, pos,
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_flash_prefill(one_chip):
+    q = _shape(one_chip, (1, PROMPT, HKV, G, HD), CFG.dtype)
+    kv = _shape(one_chip, (1, PROMPT, HKV, HD), CFG.dtype)
+    text = _compiled_text(
+        functools.partial(flash_prefill, interpret=False), q, kv, kv
+    )
+    assert "tpu_custom_call" in text
+
+
+def test_suffix_prefill_fp(one_chip):
+    q, kv, pool, table, starts, _ = _suffix_args(one_chip, CFG.dtype)
+    fn = functools.partial(suffix_prefill, prefix_width=32, interpret=False)
+    text = _compiled_text(fn, q, kv, kv, pool, pool, table, starts)
+    assert "tpu_custom_call" in text
+
+
+def test_suffix_prefill_int8(one_chip):
+    q, kv, pool, table, starts, scale = _suffix_args(one_chip, jnp.int8)
+    fn = functools.partial(suffix_prefill, prefix_width=32, interpret=False)
+    text = _compiled_text(
+        lambda *a: fn(*a[:7], pool_k_scale=a[7], pool_v_scale=a[8]),
+        q, kv, kv, pool, pool, table, starts, scale, scale,
+    )
+    assert "tpu_custom_call" in text
