@@ -46,6 +46,7 @@ def sq_norm(x: jax.Array, *, interpret: bool | None = None) -> jax.Array:
         in_specs=[pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((1, 1), lambda i: (0, 0)),
         interpret=resolve_interpret(interpret),
+        name="dp_sq_norm",
     )(x)
     return out[0, 0]
 
@@ -75,4 +76,5 @@ def clip_noise(
         ],
         out_specs=pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
         interpret=resolve_interpret(interpret),
+        name="dp_clip_noise",
     )(x, scale.reshape(1, 1), noise)

@@ -164,5 +164,6 @@ def flash_prefill(
             pltpu.VMEM((bq * g, hd), jnp.float32),
         ],
         interpret=resolve_interpret(interpret),
+        name="flash_prefill",
     )(to_head_major(q), to_head_major(k), to_head_major(v))
     return jnp.moveaxis(out.reshape(b, hkv, s, g, hd), 1, 2)

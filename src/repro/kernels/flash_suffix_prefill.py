@@ -254,5 +254,6 @@ def suffix_prefill(
         out_shape=jax.ShapeDtypeStruct((n, hkv, s * g, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=resolve_interpret(interpret),
+        name="suffix_prefill",
     )(starts, pp, table, *inputs)
     return jnp.moveaxis(out.reshape(n, hkv, s, g, hd), 1, 2)

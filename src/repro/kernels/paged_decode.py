@@ -184,6 +184,7 @@ def paged_decode(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_decode",
     )(pos_b, pages, q, k_cache, v_cache)
 
 
@@ -258,4 +259,5 @@ def _table_decode(
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, hd), q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
+        name="paged_decode",
     )(pos_b, pages, table, *inputs)

@@ -90,6 +90,7 @@ def int8_encode(x: jax.Array, *, interpret: bool | None = None):
             pl.BlockSpec((ROWS, 1), lambda i: (i, 0)),
         ),
         interpret=resolve_interpret(interpret),
+        name="int8_encode",
     )(xp)
     return q[:nb], s[:nb]
 
@@ -108,5 +109,6 @@ def int8_roundtrip(
         in_specs=[pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
         interpret=resolve_interpret(interpret),
+        name="int8_roundtrip",
     )(xp)
     return out[:nb]

@@ -158,6 +158,7 @@ def swa_decode(
         out_specs=pl.BlockSpec((1, hkv, g, hd), lambda b_, j: (b_, 0, 0, 0)),
         scratch_shapes=_state_scratch(hkv, g, hd),
         interpret=resolve_interpret(interpret),
+        name="swa_decode",
     )(
         jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1, 1), (b, 1)),
         q, k_cache, v_cache,

@@ -65,4 +65,5 @@ def topk_sparsify(
         in_specs=[pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((ROWS, BLOCK), lambda i: (i, 0)),
         interpret=resolve_interpret(interpret),
+        name="topk_compress",
     )(x)

@@ -107,6 +107,11 @@ PREFILL_MODES = ("chunked", "interleaved")
 # distinct round maximum.
 LEN_BUCKET_MIN = 8
 
+# Host spans of the engine's phases, named ``engine.<phase>``. They land on
+# the profiler's clock beside the device's events, and cost about a
+# microsecond each when no profiler session is running.
+_span = jax.profiler.TraceAnnotation
+
 
 def bucket_width(n: int, num_slots: int) -> int:
     """Round an admission-round width up to a power of two, capped at the
@@ -648,7 +653,10 @@ class ServeEngine:
 
         self.paged_cache = paged_cache
         self.preemptions = 0
-        self.occupancy: list[float] = []  # pool fill fraction per decode step
+        # pool fill fraction over decode steps: count, sum and max
+        self.occupancy_steps = 0
+        self._occupancy_sum = 0.0
+        self._occupancy_max = 0.0
         # SLO bookkeeping (both cache modes): deadline-expired queued
         # requests are recorded here as structured AdmissionErrors instead
         # of being raised (shedding happens inside the scheduler, where
@@ -849,12 +857,12 @@ class ServeEngine:
 
         def _decode_fn(p, c, t):
             self._compiles["decode"] += 1
-            with use_tensor_axis(tp_axis):
+            with _span("engine.retrace.decode"), use_tensor_axis(tp_axis):
                 return serve_model.decode(p, c, t, window=window)
 
         def _prefill_fn(p, c, t, s):
             self._compiles["prefill"] += 1
-            with use_tensor_axis(tp_axis):
+            with _span("engine.retrace.prefill"), use_tensor_axis(tp_axis):
                 return serve_model.prefill_slot(p, c, t, s, window=window)
 
         self._decode = jax.jit(_shard(_decode_fn, 1), donate_argnums=donate)
@@ -862,7 +870,8 @@ class ServeEngine:
         if model.prefill_slots is not None:
             def _prefill_slots_fn(p, c, t, l, s):
                 self._compiles["prefill_slots"] += 1
-                with use_tensor_axis(tp_axis):
+                with _span("engine.retrace.prefill_slots"), \
+                        use_tensor_axis(tp_axis):
                     return serve_model.prefill_slots(
                         p, c, t, l, s, window=window
                     )
@@ -878,7 +887,8 @@ class ServeEngine:
             # prefix_pages) triples
             def _prefill_suffix_fn(p, c, t, l, s, st, pw):
                 self._compiles["prefill_suffix"] += 1
-                with use_tensor_axis(tp_axis):
+                with _span("engine.retrace.prefill_suffix"), \
+                        use_tensor_axis(tp_axis):
                     return serve_model.prefill_slots(
                         p, c, t, l, s, starts=st, prefix_pages=pw,
                         window=window,
@@ -1030,10 +1040,11 @@ class ServeEngine:
             # trace — tests pin greedy identity through this entry)
             def _spec_verify_fn(p, c, t, l, s, st, pw):
                 self._compiles["spec_verify"] += 1
-                return serve_model.prefill_slots(
-                    p, c, t, l, s, starts=st, prefix_pages=pw,
-                    window=window, return_all_logits=True,
-                )
+                with _span("engine.retrace.spec_verify"):
+                    return serve_model.prefill_slots(
+                        p, c, t, l, s, starts=st, prefix_pages=pw,
+                        window=window, return_all_logits=True,
+                    )
 
             self._spec_verify = jax.jit(
                 _spec_verify_fn, donate_argnums=donate, static_argnums=(6,),
@@ -1128,7 +1139,9 @@ class ServeEngine:
         self.shed.clear()
         self.shed_requests = 0
         self.timeouts = 0
-        self.occupancy = []
+        self.occupancy_steps = 0
+        self._occupancy_sum = 0.0
+        self._occupancy_max = 0.0
         self.prefix_hit_pages = 0
         self.prefix_hit_tokens = 0
         self.prefix_lookup_tokens = 0
@@ -1227,7 +1240,7 @@ class ServeEngine:
         fraction since the last ``reset_metrics``."""
         if not self.paged_cache:
             return None
-        occ = self.occupancy
+        n = self.occupancy_steps
         return {
             "shards": self.num_shards,
             "mesh_axes": (
@@ -1249,8 +1262,8 @@ class ServeEngine:
             "preemptions": self.preemptions,
             "shed_requests": self.shed_requests,
             "timeouts": self.timeouts,
-            "occupancy_mean": float(np.mean(occ)) if occ else 0.0,
-            "occupancy_max": float(np.max(occ)) if occ else 0.0,
+            "occupancy_mean": self._occupancy_sum / n if n else 0.0,
+            "occupancy_max": self._occupancy_max if n else 0.0,
             "prefix_cache": self.prefix_cache,
             "prefix_cache_enabled": self.prefix_cache,
             "prefix_disabled_reason": self.prefix_disabled_reason,
@@ -1492,44 +1505,45 @@ class ServeEngine:
                             )
                         if self.pool.available < n_need + hold:
                             break  # stays queued; recompute needs no fewer
-                    pages = self.pool.alloc(n_need)
-                    self.waiting.popleft()
-                    i = free.pop(0)
-                    self._resume.pop(req.uid)
-                    self._restore_pages(
-                        pages, self.host.pop(resume.host_key)
-                    )
-                    self.swapped_in_pages += n_need
-                    self._slot_pages[i] = pages
-                    self._table_np[i, :] = 0
-                    self._table_np[i, : n_need] = pages
-                    self._table_dirty = True
-                    self.cache = {
-                        **self.cache,
-                        "pos": self.cache["pos"].at[i].set(resume.pos),
-                    }
-                    # written tokens = stream[:pos]; the slot re-feeds
-                    # stream[pos] next step and (for a mid-prefill victim)
-                    # teacher-forces the remaining prompt through pending
-                    stream = [int(t) for t in req.prompt] + list(
-                        resume.generated
-                    )
-                    slot = _Slot(
-                        req=req,
-                        pending=collections.deque(stream[resume.pos + 1:]),
-                        generated=list(resume.generated),
-                        next_feed=stream[resume.pos],
-                        admit_time=resume.admit_time,
-                        key=resume.key,
-                        feed=None,
-                        prefix_len=0,
-                    )
-                    slot.first_token_time = resume.first_token_time
-                    slot.pos_host = resume.pos
-                    self._admit_seq += 1
-                    slot.seq = self._admit_seq
-                    self.slot_history.setdefault(req.uid, []).append(i)
-                    self.slots[i] = slot
+                    with _span("engine.claim", uid=req.uid):
+                        pages = self.pool.alloc(n_need)
+                        self.waiting.popleft()
+                        i = free.pop(0)
+                        self._resume.pop(req.uid)
+                        self._restore_pages(
+                            pages, self.host.pop(resume.host_key)
+                        )
+                        self.swapped_in_pages += n_need
+                        self._slot_pages[i] = pages
+                        self._table_np[i, :] = 0
+                        self._table_np[i, : n_need] = pages
+                        self._table_dirty = True
+                        self.cache = {
+                            **self.cache,
+                            "pos": self.cache["pos"].at[i].set(resume.pos),
+                        }
+                        # written tokens = stream[:pos]; the slot re-feeds
+                        # stream[pos] next step and (for a mid-prefill victim)
+                        # teacher-forces the remaining prompt through pending
+                        stream = [int(t) for t in req.prompt] + list(
+                            resume.generated
+                        )
+                        slot = _Slot(
+                            req=req,
+                            pending=collections.deque(stream[resume.pos + 1:]),
+                            generated=list(resume.generated),
+                            next_feed=stream[resume.pos],
+                            admit_time=resume.admit_time,
+                            key=resume.key,
+                            feed=None,
+                            prefix_len=0,
+                        )
+                        slot.first_token_time = resume.first_token_time
+                        slot.pos_host = resume.pos
+                        self._admit_seq += 1
+                        slot.seq = self._admit_seq
+                        self.slot_history.setdefault(req.uid, []).append(i)
+                        self.slots[i] = slot
                     continue
                 feed = req.prompt
                 if resume is not None and resume.generated:
@@ -1577,79 +1591,81 @@ class ServeEngine:
                         if self.pool.available < n_fresh + hold:
                             self.pool.free(hits)  # undo the shares
                             break  # request stays queued
-                self.waiting.popleft()
-                i = free.pop(0)
-                self.cache = reset_slot(self.cache, i)
-                slot = _Slot(
-                    req=req,
-                    pending=collections.deque(feed.tolist()),
-                    generated=[],
-                    next_feed=-1,
-                    admit_time=now,
-                    key=self._request_key(req),
-                    feed=feed,
-                    prefix_len=suffix_start,
-                )
-                self._admit_seq += 1
-                slot.seq = self._admit_seq
-                if self.paged_cache:
-                    self._table_np[i, :] = 0
-                    if self.prefill_mode == "chunked":
-                        pages = list(hits)
-                        if cow:
-                            # the suffix overwrites the tail of the last
-                            # shared page: split it (copy-on-write) so the
-                            # index copy stays immutable
-                            src = pages[-1]
-                            dst = self.pool.alloc(1)[0]
-                            self.cache = self._copy_page(
-                                self.cache,
-                                jnp.asarray(src, jnp.int32),
-                                jnp.asarray(dst, jnp.int32),
-                            )
-                            self.pool.free([src])  # drop our share
-                            pages[-1] = dst
-                            self.cow_copies += 1
-                        fresh = self.pool.alloc(total_pages - len(pages))
-                        pages.extend(fresh)
-                        self._slot_pages[i] = pages
-                        self._table_np[i, : len(pages)] = pages
-                        if resume is None:
-                            self.prefix_hit_pages += len(hits)
-                            self.prefix_hit_tokens += suffix_start
-                            self.prefix_lookup_tokens += len(feed)
+                with _span("engine.claim", uid=req.uid):
+                    self.waiting.popleft()
+                    i = free.pop(0)
+                    self.cache = reset_slot(self.cache, i)
+                    slot = _Slot(
+                        req=req,
+                        pending=collections.deque(feed.tolist()),
+                        generated=[],
+                        next_feed=-1,
+                        admit_time=now,
+                        key=self._request_key(req),
+                        feed=feed,
+                        prefix_len=suffix_start,
+                    )
+                    self._admit_seq += 1
+                    slot.seq = self._admit_seq
+                    if self.paged_cache:
+                        self._table_np[i, :] = 0
+                        if self.prefill_mode == "chunked":
+                            pages = list(hits)
+                            if cow:
+                                # the suffix overwrites the tail of the last
+                                # shared page: split it (copy-on-write) so the
+                                # index copy stays immutable
+                                src = pages[-1]
+                                dst = self.pool.alloc(1)[0]
+                                self.cache = self._copy_page(
+                                    self.cache,
+                                    jnp.asarray(src, jnp.int32),
+                                    jnp.asarray(dst, jnp.int32),
+                                )
+                                self.pool.free([src])  # drop our share
+                                pages[-1] = dst
+                                self.cow_copies += 1
+                            fresh = self.pool.alloc(total_pages - len(pages))
+                            pages.extend(fresh)
+                            self._slot_pages[i] = pages
+                            self._table_np[i, : len(pages)] = pages
+                            if resume is None:
+                                self.prefix_hit_pages += len(hits)
+                                self.prefix_hit_tokens += suffix_start
+                                self.prefix_lookup_tokens += len(feed)
+                            else:
+                                # a resume replays tokens the engine
+                                # itself published — its (near-total) hit
+                                # is real work saved but says nothing
+                                # about cross-request sharing, so it must
+                                # not inflate the external prefix_hit_rate
+                                self.prefix_resume_hit_tokens += suffix_start
                         else:
-                            # a resume replays tokens the engine itself
-                            # published — its (near-total) hit is real work
-                            # saved but says nothing about cross-request
-                            # sharing, so it must not inflate the external
-                            # prefix_hit_rate
-                            self.prefix_resume_hit_tokens += suffix_start
-                    else:
-                        self._slot_pages[i] = []
-                    self._table_dirty = True
-                if resume is not None:
-                    # resume restoration is cache-layout independent: the
-                    # re-prefill of prompt + generated[:-1] (the feed built
-                    # above) works over rings and page tables alike, so a
-                    # router may migrate paged-engine state into any engine
-                    self._resume.pop(req.uid)
-                    slot.generated = list(resume.generated)
-                    slot.key = resume.key
-                    slot.first_token_time = resume.first_token_time
-                    slot.admit_time = resume.admit_time
-                    slot.resumed = bool(resume.generated)
-                self.slot_history.setdefault(req.uid, []).append(i)
-                self.slots[i] = slot
-                if self.prefill_mode == "chunked":
-                    slot.pos_host = len(feed)
-                    claimed.append(i)
-                else:  # interleaved: decode step consumes prompt tokens
-                    slot.pos_host = 0
-                    slot.next_feed = slot.pending.popleft()
+                            self._slot_pages[i] = []
+                        self._table_dirty = True
+                    if resume is not None:
+                        # resume restoration is cache-layout independent: the
+                        # re-prefill of prompt + generated[:-1] (the feed built
+                        # above) works over rings and page tables alike, so a
+                        # router may migrate paged-engine state into any engine
+                        self._resume.pop(req.uid)
+                        slot.generated = list(resume.generated)
+                        slot.key = resume.key
+                        slot.first_token_time = resume.first_token_time
+                        slot.admit_time = resume.admit_time
+                        slot.resumed = bool(resume.generated)
+                    self.slot_history.setdefault(req.uid, []).append(i)
+                    self.slots[i] = slot
+                    if self.prefill_mode == "chunked":
+                        slot.pos_host = len(feed)
+                        claimed.append(i)
+                    else:  # interleaved: decode step consumes prompt tokens
+                        slot.pos_host = 0
+                        slot.next_feed = slot.pending.popleft()
             if not claimed:
                 return
-            retired = self._prefill_claimed(claimed)
+            with _span("engine.prefill", rows=len(claimed)):
+                retired = self._prefill_claimed(claimed)
             if not retired:
                 return  # no slot freed, nothing more to admit this round
 
@@ -1805,48 +1821,47 @@ class ServeEngine:
         return len(slot.generated) >= slot.req.max_new_tokens
 
     def _retire(self, i: int, slot: _Slot) -> None:
-        reason = (
-            "eos"
-            if self.eos_id is not None and slot.generated[-1] == self.eos_id
-            else "length"
-        )
-        self.finished.append(
-            RequestOutput(
-                uid=slot.req.uid,
-                prompt=slot.req.prompt.tolist(),
-                tokens=list(slot.generated),
-                slot=i,
-                finish_reason=reason,
-                arrival_time=slot.req.arrival_time,
-                admit_time=slot.admit_time,
-                first_token_time=slot.first_token_time,
-                finish_time=self._now(),
+        with _span("engine.retire", uid=slot.req.uid):
+            eos = self.eos_id is not None and slot.generated[-1] == self.eos_id
+            reason = "eos" if eos else "length"
+            self.finished.append(
+                RequestOutput(
+                    uid=slot.req.uid,
+                    prompt=slot.req.prompt.tolist(),
+                    tokens=list(slot.generated),
+                    slot=i,
+                    finish_reason=reason,
+                    arrival_time=slot.req.arrival_time,
+                    admit_time=slot.admit_time,
+                    first_token_time=slot.first_token_time,
+                    finish_time=self._now(),
+                )
             )
-        )
-        self.slots[i] = None
-        if self.draft is not None:
-            self._draft_pos[i] = -1  # next occupant must re-sync the draft
-        if self.paged_cache:
-            if self.prefix is not None:
-                # publish the slot's FULL prompt pages into the prefix
-                # index (the index takes its own refs) BEFORE dropping the
-                # slot's — already-indexed chunks dedupe to their existing
-                # physical page. Generated tokens and partial tail pages
-                # are never indexed.
-                n_pub = len(slot.req.prompt) // self.page_size
-                n_pub = min(n_pub, len(self._slot_pages[i]))
-                if n_pub > 0:
-                    self.prefix.insert(
-                        slot.req.prompt, self._slot_pages[i][:n_pub]
-                    )
-            # the slot's refs return to the pool for IMMEDIATE reuse (pages
-            # the index pinned stay live); the table row reverts to the
-            # scratch page so the retired slot's drifting ``pos`` writes
-            # nothing anyone reads
-            self.pool.free(self._slot_pages[i])
-            self._slot_pages[i] = []
-            self._table_np[i, :] = 0
-            self._table_dirty = True
+            self.slots[i] = None
+            if self.draft is not None:
+                # the next occupant must re-sync the draft
+                self._draft_pos[i] = -1
+            if self.paged_cache:
+                if self.prefix is not None:
+                    # publish the slot's FULL prompt pages into the
+                    # prefix index (the index takes its own refs) BEFORE
+                    # dropping the slot's — already-indexed chunks dedupe
+                    # to their existing physical page. Generated tokens
+                    # and partial tail pages are never indexed.
+                    n_pub = len(slot.req.prompt) // self.page_size
+                    n_pub = min(n_pub, len(self._slot_pages[i]))
+                    if n_pub > 0:
+                        self.prefix.insert(
+                            slot.req.prompt, self._slot_pages[i][:n_pub]
+                        )
+                # the slot's refs return to the pool for IMMEDIATE reuse
+                # (pages the index pinned stay live); the table row
+                # reverts to the scratch page so the retired slot's
+                # drifting ``pos`` writes nothing anyone reads
+                self.pool.free(self._slot_pages[i])
+                self._slot_pages[i] = []
+                self._table_np[i, :] = 0
+                self._table_dirty = True
 
     def _retire_timeout(self, i: int, slot: _Slot) -> None:
         """Watchdog retirement: the slot exceeded ``max_wall_s`` of wall
@@ -1856,28 +1871,29 @@ class ServeEngine:
         Pages are freed WITHOUT publishing to the prefix index — a
         mid-prefill (interleaved) timeout may hold a partially written
         final page, which must never be aliased by another request."""
-        self.timeouts += 1
-        self.finished.append(
-            RequestOutput(
-                uid=slot.req.uid,
-                prompt=slot.req.prompt.tolist(),
-                tokens=list(slot.generated),
-                slot=i,
-                finish_reason="timeout",
-                arrival_time=slot.req.arrival_time,
-                admit_time=slot.admit_time,
-                first_token_time=slot.first_token_time,
-                finish_time=self._now(),
+        with _span("engine.retire", uid=slot.req.uid):
+            self.timeouts += 1
+            self.finished.append(
+                RequestOutput(
+                    uid=slot.req.uid,
+                    prompt=slot.req.prompt.tolist(),
+                    tokens=list(slot.generated),
+                    slot=i,
+                    finish_reason="timeout",
+                    arrival_time=slot.req.arrival_time,
+                    admit_time=slot.admit_time,
+                    first_token_time=slot.first_token_time,
+                    finish_time=self._now(),
+                )
             )
-        )
-        self.slots[i] = None
-        if self.draft is not None:
-            self._draft_pos[i] = -1
-        if self.paged_cache:
-            self.pool.free(self._slot_pages[i])
-            self._slot_pages[i] = []
-            self._table_np[i, :] = 0
-            self._table_dirty = True
+            self.slots[i] = None
+            if self.draft is not None:
+                self._draft_pos[i] = -1
+            if self.paged_cache:
+                self.pool.free(self._slot_pages[i])
+                self._slot_pages[i] = []
+                self._table_np[i, :] = 0
+                self._table_dirty = True
 
     def _watchdog(self) -> None:
         """Per-request wall-clock guard (``max_wall_s``): retire any live
@@ -1915,32 +1931,37 @@ class ServeEngine:
         """Copy page CONTENT device→host: name → (L, n, page, …) numpy.
         ``np.asarray`` blocks until the copy lands, so callers may free
         (and let the pool rewrite) the source pages immediately after."""
-        n = len(pages)
-        m = self._page_bucket(n)
-        idx = jnp.asarray(np.asarray(list(pages) + [0] * (m - n), np.int32))
-        arrs = self._gather_pages_jit(self.cache, idx)
-        return {
-            name: np.asarray(a[:, :n])
-            for name, a in zip(self._kv_names, arrs)
-        }
+        with _span("engine.swap_out", pages=len(pages)):
+            n = len(pages)
+            m = self._page_bucket(n)
+            idx = np.asarray(list(pages) + [0] * (m - n), np.int32)
+            idx = jnp.asarray(idx)
+            arrs = self._gather_pages_jit(self.cache, idx)
+            return {
+                name: np.asarray(a[:, :n])
+                for name, a in zip(self._kv_names, arrs)
+            }
 
     def _restore_pages(self, pages: list[int], arrays: dict) -> None:
         """Scatter host content back into freshly allocated pool pages.
         Bucket padding targets scratch page 0 (reserved: writes harmless,
         never validly read)."""
-        n = len(pages)
-        m = self._page_bucket(n)
-        idx = np.asarray(list(pages) + [0] * (m - n), np.int32)
-        arrs = []
-        for name in self._kv_names:
-            a = arrays[name]
-            if m > n:
-                pad = np.zeros((a.shape[0], m - n) + a.shape[2:], a.dtype)
-                a = np.concatenate([a, pad], axis=1)
-            arrs.append(jnp.asarray(a))
-        self.cache = self._scatter_pages_jit(
-            self.cache, jnp.asarray(idx), tuple(arrs)
-        )
+        with _span("engine.swap_in", pages=len(pages)):
+            n = len(pages)
+            m = self._page_bucket(n)
+            idx = np.asarray(list(pages) + [0] * (m - n), np.int32)
+            arrs = []
+            for name in self._kv_names:
+                a = arrays[name]
+                if m > n:
+                    pad = np.zeros(
+                        (a.shape[0], m - n) + a.shape[2:], a.dtype
+                    )
+                    a = np.concatenate([a, pad], axis=1)
+                arrs.append(jnp.asarray(a))
+            self.cache = self._scatter_pages_jit(
+                self.cache, jnp.asarray(idx), tuple(arrs)
+            )
 
     def _demote_prefix_page(self, key: tuple, page: int) -> None:
         """PrefixCache eviction hook: copy the page's content to the host
@@ -2162,7 +2183,8 @@ class ServeEngine:
                 if self.prefix is not None and self.prefix.evict(1) > 0:
                     continue
                 victim = self._preempt_victim()
-                self._preempt(victim)
+                with _span("engine.preempt", uid=self.slots[victim].req.uid):
+                    self._preempt(victim)
                 if victim == i:
                     break  # the needy slot itself went back to the queue
 
@@ -2418,7 +2440,13 @@ class ServeEngine:
         self.draft.commit(
             jnp.asarray(mask), jnp.asarray(new_pos), jnp.asarray(snap_idx)
         )
-        self.occupancy.append(self.pool.in_use / max(self.pool.capacity, 1))
+        self._record_occupancy()
+
+    def _record_occupancy(self) -> None:
+        occ = self.pool.in_use / max(self.pool.capacity, 1)
+        self.occupancy_steps += 1
+        self._occupancy_sum += occ
+        self._occupancy_max = max(self._occupancy_max, occ)
 
     def step(self, *, respect_arrivals: bool = False) -> list[RequestOutput]:
         """One engine iteration: admit → one batched decode step → retire.
@@ -2427,111 +2455,148 @@ class ServeEngine:
         ``respect_arrivals`` the admission gate compares each request's
         ``arrival_time`` against the engine clock; otherwise the queue
         drains in arrival order as slots free up (virtual time).
+
+        Each phase runs under its own host span (``engine.admit``,
+        ``engine.pages``, ``engine.dispatch_decode``, ``engine.read_tokens``,
+        ``engine.emit`` or ``engine.spec_round``) inside ``engine.step``.
         """
         n_done = len(self.finished)
-        attention.set_decode_kernel(self.use_kernel, paged=self.paged_decode)
-        # prefix-hit admission rounds (dispatched from _admit below) run
-        # the Pallas suffix-prefill kernel under the same engine-wide flag
-        attention.set_suffix_kernel(self.use_kernel)
-        try:
-            self._watchdog()
-            self._admit(self._now(), respect_arrivals)
-            live = [i for i, s in enumerate(self.slots) if s is not None]
-            if live and self.paged_cache:
-                # lazy page allocation (may preempt the youngest slot when
-                # the pool runs dry — re-collect the survivors)
-                self._ensure_decode_pages(live)
+        with _span("engine.step"):
+            attention.set_decode_kernel(
+                self.use_kernel, paged=self.paged_decode
+            )
+            # prefix-hit admission rounds (dispatched from _admit below) run
+            # the Pallas suffix-prefill kernel under the same engine-wide flag
+            attention.set_suffix_kernel(self.use_kernel)
+            try:
+                with _span("engine.admit") as span:
+                    seq = self._admit_seq
+                    self._watchdog()
+                    self._admit(self._now(), respect_arrivals)
+                    span.set_metadata(claimed=self._admit_seq - seq)
                 live = [i for i, s in enumerate(self.slots) if s is not None]
-            if live and self.draft is not None:
-                # speculative round: draft k tokens per slot, verify all of
-                # them in one batched target dispatch (see _spec_round)
-                self._spec_round(live)
-            elif live:
-                self._sync_table()
-                feed = np.zeros((self.num_slots, 1), np.int32)
-                for i in live:
-                    feed[i, 0] = self.slots[i].next_feed
-                self.cache, logits = self._decode(
-                    self.params, self.cache, jnp.asarray(feed)
-                )
-                self.steps += 1
-                for i in live:
-                    self.slots[i].pos_host += 1  # one token written per row
-                if self.paged_cache:
-                    self.occupancy.append(
-                        self.pool.in_use / max(self.pool.capacity, 1)
-                    )
-                # one batched argmax + host transfer per step, not per slot
-                # (skipped entirely when every emitting slot samples).
-                # Resumed slots re-feed a stored token this step: no argmax,
-                # no sampling draw — their streams must not advance.
-                need_greedy = any(
-                    self.slots[i].key is None and not self.slots[i].pending
-                    and not self.slots[i].resumed
-                    for i in live
-                )
-                greedy = (
-                    np.asarray(jnp.argmax(logits[:, : self.cfg.vocab_size], axis=-1))
-                    if need_greedy
-                    else None
-                )
-                # sampled slots batch the same way: split every stream and
-                # draw in ONE fixed-width dispatch (dummy rows for greedy /
-                # mid-prefill slots), then one host transfer
-                samp = [
-                    i for i in live
-                    if self.slots[i].key is not None
-                    and not self.slots[i].pending
-                    and not self.slots[i].resumed
-                ]
-                sampled: dict[int, int] = {}
-                if samp:
-                    keys, temps, ks, ps = [], [], [], []
-                    for i in range(self.num_slots):
-                        if i in samp:
-                            sp = self.slots[i].req.sampling
-                            keys.append(self.slots[i].key)
-                            temps.append(sp.temperature)
-                            ks.append(sp.top_k)
-                            ps.append(sp.top_p)
-                        else:
-                            keys.append(self._dummy_key)
-                            temps.append(1.0)
-                            ks.append(1)
-                            ps.append(1.0)
-                    new_keys, toks = self._sample_rows(
-                        jnp.stack(keys), logits,
-                        jnp.asarray(temps, jnp.float32),
-                        jnp.asarray(ks, jnp.int32),
-                        jnp.asarray(ps, jnp.float32),
-                    )
-                    toks = np.asarray(toks)
-                    for i in samp:
-                        self.slots[i].key = new_keys[i]
-                        sampled[i] = int(toks[i])
-                now = self._now()
-                for i in live:
-                    slot = self.slots[i]
-                    if slot.pending:  # mid-prefill: logits are teacher-forced
-                        slot.next_feed = slot.pending.popleft()
-                        continue
-                    if slot.resumed:
-                        # interleaved resume just finished re-feeding its
-                        # history: the next token is already known
-                        slot.resumed = False
-                        slot.next_feed = slot.generated[-1]
-                        continue
-                    g = sampled[i] if slot.key is not None else int(greedy[i])
-                    if slot.first_token_time < 0:
-                        slot.first_token_time = now
-                    slot.generated.append(g)
-                    slot.next_feed = g
-                    if self._done(slot, g):
-                        self._retire(i, slot)  # freed; backfilled next admit
-        finally:
-            attention.set_decode_kernel(False)
-            attention.set_suffix_kernel(False)
+                if live and self.paged_cache:
+                    # lazy page allocation (may preempt the youngest slot
+                    # when the pool runs dry — re-collect the survivors)
+                    with _span("engine.pages"):
+                        self._ensure_decode_pages(live)
+                    live = [
+                        i for i, s in enumerate(self.slots) if s is not None
+                    ]
+                if live and self.draft is not None:
+                    # speculative round: draft k tokens per slot, verify all
+                    # of them in one batched target dispatch (_spec_round)
+                    with _span("engine.spec_round", rows=len(live)):
+                        self._spec_round(live)
+                elif live:
+                    with _span("engine.dispatch_decode", rows=len(live)):
+                        logits = self._dispatch_decode(live)
+                    with _span("engine.read_tokens"):
+                        greedy, sampled = self._read_tokens(live, logits)
+                    with _span("engine.emit"):
+                        self._emit(live, greedy, sampled)
+            finally:
+                attention.set_decode_kernel(False)
+                attention.set_suffix_kernel(False)
         return self.finished[n_done:]
+
+    def _dispatch_decode(self, live: list[int]) -> jax.Array:
+        """Launch one decode step over every slot (live rows fed their next
+        token); returns the step's logits without waiting for them."""
+        self._sync_table()
+        feed = np.zeros((self.num_slots, 1), np.int32)
+        for i in live:
+            feed[i, 0] = self.slots[i].next_feed
+        self.cache, logits = self._decode(
+            self.params, self.cache, jnp.asarray(feed)
+        )
+        self.steps += 1
+        for i in live:
+            self.slots[i].pos_host += 1  # one token written per row
+        if self.paged_cache:
+            self._record_occupancy()
+        return logits
+
+    def _read_tokens(
+        self, live: list[int], logits: jax.Array
+    ) -> tuple[np.ndarray | None, dict[int, int]]:
+        """The step's tokens on the host: the greedy rows' argmax and the
+        sampled rows' draws, each batched into one host transfer."""
+        # one batched argmax + host transfer per step, not per slot
+        # (skipped entirely when every emitting slot samples).
+        # Resumed slots re-feed a stored token this step: no argmax,
+        # no sampling draw — their streams must not advance.
+        need_greedy = any(
+            self.slots[i].key is None and not self.slots[i].pending
+            and not self.slots[i].resumed
+            for i in live
+        )
+        greedy = (
+            np.asarray(jnp.argmax(logits[:, : self.cfg.vocab_size], axis=-1))
+            if need_greedy
+            else None
+        )
+        # sampled slots batch the same way: split every stream and
+        # draw in ONE fixed-width dispatch (dummy rows for greedy /
+        # mid-prefill slots), then one host transfer
+        samp = [
+            i for i in live
+            if self.slots[i].key is not None
+            and not self.slots[i].pending
+            and not self.slots[i].resumed
+        ]
+        sampled: dict[int, int] = {}
+        if samp:
+            keys, temps, ks, ps = [], [], [], []
+            for i in range(self.num_slots):
+                if i in samp:
+                    sp = self.slots[i].req.sampling
+                    keys.append(self.slots[i].key)
+                    temps.append(sp.temperature)
+                    ks.append(sp.top_k)
+                    ps.append(sp.top_p)
+                else:
+                    keys.append(self._dummy_key)
+                    temps.append(1.0)
+                    ks.append(1)
+                    ps.append(1.0)
+            new_keys, toks = self._sample_rows(
+                jnp.stack(keys), logits,
+                jnp.asarray(temps, jnp.float32),
+                jnp.asarray(ks, jnp.int32),
+                jnp.asarray(ps, jnp.float32),
+            )
+            toks = np.asarray(toks)
+            for i in samp:
+                self.slots[i].key = new_keys[i]
+                sampled[i] = int(toks[i])
+        return greedy, sampled
+
+    def _emit(
+        self, live: list[int], greedy: np.ndarray | None,
+        sampled: dict[int, int],
+    ) -> None:
+        """Per-slot bookkeeping after the read: advance mid-prefill and
+        resumed rows, append each emitted token, retire finished rows."""
+        now = self._now()
+        for i in live:
+            slot = self.slots[i]
+            if slot.pending:  # mid-prefill: logits are teacher-forced
+                slot.next_feed = slot.pending.popleft()
+                continue
+            if slot.resumed:
+                # interleaved resume just finished re-feeding its
+                # history: the next token is already known
+                slot.resumed = False
+                slot.next_feed = slot.generated[-1]
+                continue
+            g = sampled[i] if slot.key is not None else int(greedy[i])
+            if slot.first_token_time < 0:
+                slot.first_token_time = now
+            slot.generated.append(g)
+            slot.next_feed = g
+            if self._done(slot, g):
+                self._retire(i, slot)  # freed; backfilled next admit
 
     def run(
         self, requests=(), *, realtime: bool = False

@@ -439,15 +439,28 @@ def test_pool_stats_and_occupancy_trace(model_and_params):
     ring = _build(model_and_params)
     assert ring.pool_stats is None
     engine = _build(model_and_params, paged_cache=True, page_size=4)
+    # the pool fill each decode dispatch sees, recorded beside the engine's
+    # own running count, sum and max
+    seen = []
+    decode = engine._decode
+
+    def observed(*a):
+        seen.append(engine.pool.in_use / engine.pool.capacity)
+        return decode(*a)
+
+    engine._decode = observed
     engine.run(_reqs(cfg, [P, P, 5]))
     stats = engine.pool_stats
     assert stats["pages_in_use"] == 0
     assert stats["peak_pages_in_use"] > 0
     assert 0.0 < stats["occupancy_max"] <= 1.0
-    assert len(engine.occupancy) == engine.steps
+    assert engine.occupancy_steps == engine.steps == len(seen)
+    assert stats["occupancy_mean"] == pytest.approx(np.mean(seen))
+    assert stats["occupancy_max"] == max(seen)
     engine.reset_metrics()
     assert engine.pool_stats["occupancy_max"] == 0.0
-    assert engine.occupancy == []
+    assert engine.pool_stats["occupancy_mean"] == 0.0
+    assert engine.occupancy_steps == 0
 
 
 def test_paged_cache_specs_shapes(model_and_params):

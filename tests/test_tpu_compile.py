@@ -6,7 +6,8 @@ lowering refuses, so these tests compile every serving kernel with
 stablelm-1.6b widths (32 kv heads of head_dim 64, bf16, page 16). Nothing
 runs: a compile that passes here proves the Mosaic lowering and the
 compiler accept the kernel, and each test checks the kernel is really in
-the program (``tpu_custom_call``).
+the program (``tpu_custom_call``) under its stable ``pallas_call`` name,
+the name a profiler trace gives the kernel's op.
 
 The topology is described inside a module-scoped fixture (never at import:
 only one process at a time may load the TPU library, and test workers
@@ -14,6 +15,7 @@ import every test file), and the persistent compilation cache is off
 around these compiles — an entry written for a described chip cannot be
 read back on a host without one."""
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +26,7 @@ from repro.configs import get_config
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.flash_suffix_prefill import suffix_prefill
 from repro.kernels.paged_decode import paged_decode
+from repro.kernels.quantize import int8_encode, int8_roundtrip
 from repro.kernels.swa_decode import swa_decode
 
 CFG = get_config("stablelm-1.6b")
@@ -65,6 +68,16 @@ def _compiled_text(fn, *args) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _kernel_names(text: str) -> set[str]:
+    """Instruction names of the program's Pallas custom calls, their
+    ``.N`` suffix dropped."""
+    return {
+        re.match(r"\s*(?:ROOT )?%([\w-]+?)(?:\.\d+)? = ", line).group(1)
+        for line in text.splitlines()
+        if 'custom_call_target="tpu_custom_call"' in line
+    }
+
+
 def _decode_args(sh, kv_dtype):
     q = _shape(sh, (SLOTS, HKV, G, HD), CFG.dtype)
     pool = _shape(sh, (N_PAGES, PAGE, HKV, HD), kv_dtype)
@@ -91,6 +104,7 @@ def test_paged_decode_table_fp(one_chip):
         lambda q, k, v, p, t: fn(q, k, v, p, table=t), q, pool, pool, pos, table
     )
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"paged_decode"}
 
 
 def test_paged_decode_table_int8(one_chip):
@@ -103,6 +117,7 @@ def test_paged_decode_table_int8(one_chip):
         q, pool, pool, pos, table, scale, scale,
     )
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"paged_decode"}
 
 
 def test_paged_decode_ring(one_chip):
@@ -113,6 +128,7 @@ def test_paged_decode_ring(one_chip):
         functools.partial(paged_decode, interpret=False), q, ring, ring, pos
     )
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"paged_decode"}
 
 
 def test_swa_decode(one_chip):
@@ -124,6 +140,7 @@ def test_swa_decode(one_chip):
         q, ring, ring, pos,
     )
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"swa_decode"}
 
 
 def test_flash_prefill(one_chip):
@@ -133,6 +150,7 @@ def test_flash_prefill(one_chip):
         functools.partial(flash_prefill, interpret=False), q, kv, kv
     )
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"flash_prefill"}
 
 
 def test_suffix_prefill_fp(one_chip):
@@ -140,6 +158,7 @@ def test_suffix_prefill_fp(one_chip):
     fn = functools.partial(suffix_prefill, prefix_width=32, interpret=False)
     text = _compiled_text(fn, q, kv, kv, pool, pool, table, starts)
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"suffix_prefill"}
 
 
 def test_suffix_prefill_int8(one_chip):
@@ -150,3 +169,15 @@ def test_suffix_prefill_int8(one_chip):
         q, kv, kv, pool, pool, table, starts, scale, scale,
     )
     assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"suffix_prefill"}
+
+
+@pytest.mark.parametrize("fn, name", [
+    (int8_encode, "int8_encode"), (int8_roundtrip, "int8_roundtrip"),
+])
+def test_int8_wire_kernels(one_chip, fn, name):
+    """The federated int8 wire codec: 4,096 blocks of 256 values."""
+    x = _shape(one_chip, (4096, 256), jnp.float32)
+    text = _compiled_text(functools.partial(fn, interpret=False), x)
+    assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {name}
