@@ -20,7 +20,8 @@ import pytest
 from tests._hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
-from repro.kernels.paged_decode import paged_decode
+from repro.kernels.paged_decode import paged_decode, pages_per_block, walks_pages
+from repro.kernels.quantize import kv_quant
 from repro.kernels.swa_decode import swa_decode
 
 # (cap, positions, window) covering every ring regime in one batch:
@@ -185,6 +186,21 @@ def _scatter_to_pool(kc, vc, page, key):
 # every CASES cap splits into pages of 64 — small enough that several
 # logical pages exist (real skipping + indirection) at every cap
 TABLE_PAGE = 64
+# head widths that take each table-mode path: a lane-aligned head_dim walks
+# the live pages in multi-page DMA blocks, a narrower one keeps the (B, T)
+# grid of one page a step
+WALK_HD, GRID_HD = 128, 64
+
+
+def _block_tokens(q, pool, table):
+    """Tokens in one compute block of the table kernel at these shapes: the
+    page size at which the contiguous kernel associates the online softmax
+    alike."""
+    _, hkv, _, hd = q.shape
+    page = pool.shape[1]
+    if not walks_pages(page, hkv, hd, quant=False):
+        return page
+    return page * pages_per_block(table.shape[1], page, hkv, hd, q.dtype)
 
 
 class TestTableMode:
@@ -193,13 +209,36 @@ class TestTableMode:
         self, cap, poss, window
     ):
         """Scattered physical placement must be invisible BIT FOR BIT
-        against the contiguous paged kernel at the SAME page size (same
-        chunk partitioning → same online-softmax association)."""
-        q, kc, vc = _rand(jax.random.PRNGKey(3 * cap + window), cap, len(poss))
+        against the contiguous paged kernel at the page size of the table
+        kernel's compute block (same chunk partitioning → same
+        online-softmax association)."""
+        q, kc, vc = _rand(
+            jax.random.PRNGKey(3 * cap + window), cap, len(poss), hd=WALK_HD
+        )
         pos = jnp.asarray(poss, jnp.int32)
         pool_k, pool_v, table = _scatter_to_pool(
             kc, vc, TABLE_PAGE, jax.random.PRNGKey(cap)
         )
+        out = paged_decode(q, pool_k, pool_v, pos, window, table=table)
+        expected = paged_decode(
+            q, kc, vc, pos, window, page=_block_tokens(q, pool_k, table)
+        )
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(expected))
+
+    @pytest.mark.parametrize("cap,poss,window", CASES)
+    def test_grid_kernel_bitwise_matches_contiguous_kernel(
+        self, cap, poss, window
+    ):
+        """The (B, T) grid path (head_dim off the lane width) blocks one
+        page a step: bitwise the contiguous kernel at the same page size."""
+        q, kc, vc = _rand(
+            jax.random.PRNGKey(3 * cap + window), cap, len(poss), hd=GRID_HD
+        )
+        pos = jnp.asarray(poss, jnp.int32)
+        pool_k, pool_v, table = _scatter_to_pool(
+            kc, vc, TABLE_PAGE, jax.random.PRNGKey(cap)
+        )
+        assert _block_tokens(q, pool_k, table) == TABLE_PAGE
         out = paged_decode(q, pool_k, pool_v, pos, window, table=table)
         expected = paged_decode(q, kc, vc, pos, window, page=TABLE_PAGE)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(expected))
@@ -219,7 +258,26 @@ class TestTableMode:
 
     @pytest.mark.parametrize("cap,poss,window", CASES)
     def test_table_kernel_close_to_oracle(self, cap, poss, window):
-        q, kc, vc = _rand(jax.random.PRNGKey(11 * cap + window), cap, len(poss))
+        q, kc, vc = _rand(
+            jax.random.PRNGKey(11 * cap + window), cap, len(poss), hd=WALK_HD
+        )
+        pos = jnp.asarray(poss, jnp.int32)
+        pool_k, pool_v, table = _scatter_to_pool(
+            kc, vc, TABLE_PAGE, jax.random.PRNGKey(cap + 2)
+        )
+        out = paged_decode(q, pool_k, pool_v, pos, window, table=table)
+        expected = ref.paged_table_decode_ref(
+            q, pool_k, pool_v, pos, table, window
+        )
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(expected), rtol=3e-5, atol=3e-5
+        )
+
+    @pytest.mark.parametrize("cap,poss,window", CASES)
+    def test_grid_kernel_close_to_oracle(self, cap, poss, window):
+        q, kc, vc = _rand(
+            jax.random.PRNGKey(11 * cap + window), cap, len(poss), hd=GRID_HD
+        )
         pos = jnp.asarray(poss, jnp.int32)
         pool_k, pool_v, table = _scatter_to_pool(
             kc, vc, TABLE_PAGE, jax.random.PRNGKey(cap + 2)
@@ -237,7 +295,7 @@ class TestTableMode:
         engine leaves them at scratch page 0): the index-map clamp + the
         live-page gate mean they must not change a single bit."""
         cap, page = 256, 64
-        q, kc, vc = _rand(jax.random.PRNGKey(41), cap, 3)
+        q, kc, vc = _rand(jax.random.PRNGKey(41), cap, 3, hd=WALK_HD)
         pos = jnp.asarray([10, 100, 150], jnp.int32)  # 1, 2, 3 live pages
         pool_k, pool_v, table = _scatter_to_pool(
             kc, vc, page, jax.random.PRNGKey(42)
@@ -254,7 +312,7 @@ class TestTableMode:
         """Two rows with interleaved physical pages: each row's solo run
         equals its batched row — page placement of OTHER rows can't leak."""
         cap, page = 512, 64
-        q, kc, vc = _rand(jax.random.PRNGKey(51), cap, 2)
+        q, kc, vc = _rand(jax.random.PRNGKey(51), cap, 2, hd=WALK_HD)
         pos = jnp.asarray([200, 700], jnp.int32)
         pool_k, pool_v, table = _scatter_to_pool(
             kc, vc, page, jax.random.PRNGKey(52)
@@ -276,7 +334,7 @@ class TestTableMode:
         placement, and table mode already tolerates arbitrary placement,
         so no kernel change is needed (this pins that claim)."""
         cap, page, shared_pages = 256, 64, 2
-        q, kc, vc = _rand(jax.random.PRNGKey(71), cap, 2)
+        q, kc, vc = _rand(jax.random.PRNGKey(71), cap, 2, hd=WALK_HD)
         # duplicate the shared-prefix CONTENT into both rows' caches
         kc = kc.at[1, : shared_pages * page].set(kc[0, : shared_pages * page])
         vc = vc.at[1, : shared_pages * page].set(vc[0, : shared_pages * page])
@@ -304,7 +362,7 @@ class TestTableMode:
 
     def test_ops_routes_table_mode(self):
         cap, page = 128, 64
-        q, kc, vc = _rand(jax.random.PRNGKey(61), cap, 2)
+        q, kc, vc = _rand(jax.random.PRNGKey(61), cap, 2, hd=WALK_HD)
         pos = jnp.asarray([9, 300], jnp.int32)
         pool_k, pool_v, table = _scatter_to_pool(
             kc, vc, page, jax.random.PRNGKey(62)
@@ -323,17 +381,144 @@ class TestTableMode:
     @settings(max_examples=15, deadline=None)
     def test_property_table_ring_positions(self, pos, window):
         """Arbitrary ring positions: table kernel == contiguous paged
-        kernel at the same page size, scattered placement and all."""
+        kernel at the compute block's page size, scattered placement and
+        all."""
         key = jax.random.PRNGKey(pos + 131 * window)
-        cap, page = 256, 64
-        q = jax.random.normal(key, (1, 1, 2, 64))
-        kc = jax.random.normal(jax.random.fold_in(key, 1), (1, cap, 1, 64))
-        vc = jax.random.normal(jax.random.fold_in(key, 2), (1, cap, 1, 64))
+        cap, page = 256, 16
+        # 4 kv heads of 128 in f32: 8-page blocks, two to the table
+        q = jax.random.normal(key, (1, 4, 2, WALK_HD))
+        kc = jax.random.normal(jax.random.fold_in(key, 1), (1, cap, 4, WALK_HD))
+        vc = jax.random.normal(jax.random.fold_in(key, 2), (1, cap, 4, WALK_HD))
         pool_k, pool_v, table = _scatter_to_pool(
             kc, vc, page, jax.random.fold_in(key, 3)
         )
         a = paged_decode(
             q, pool_k, pool_v, jnp.asarray(pos), window, table=table
         )
-        b = paged_decode(q, kc, vc, jnp.asarray(pos), window, page=page)
+        b = paged_decode(
+            q, kc, vc, jnp.asarray(pos), window,
+            page=_block_tokens(q, pool_k, table),
+        )
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ------------------------------------------------- page walk: block edges
+# Page 16 x 8 kv heads x 128 is 64 KiB a page in f32, so the walk blocks a
+# few pages (``pages_per_block``) and a 32-page table is several blocks;
+# page·Hkv = 128 lets the int8 pool's scale planes walk too.
+WALK_PAGE, WALK_HKV, WALK_T = 16, 8, 32
+WALK_PPB = pages_per_block(WALK_T, WALK_PAGE, WALK_HKV, WALK_HD, jnp.float32)
+# live span of the probed rows → (pos, window)
+SPANS = {
+    "one_page": (5, 0),
+    "block_edge": (WALK_PPB * WALK_PAGE - 1, 0),
+    "block_edge_plus_1": (WALK_PPB * WALK_PAGE, 0),
+    "full_table_wrapped": (WALK_T * WALK_PAGE + 100, 64),
+}
+
+
+def _walk_pool(key, n, quant):
+    """q, (k, v) pools (int8 with scales when ``quant``), their fp
+    mirror, and a scattered (n, WALK_T) table over 1 + n·WALK_T pages."""
+    ks = jax.random.split(key, 4)
+    shape = (1 + n * WALK_T, WALK_PAGE, WALK_HKV, WALK_HD)
+    q = jax.random.normal(ks[0], (n, WALK_HKV, 2, WALK_HD))
+    pk = jax.random.normal(ks[1], shape)
+    pv = jax.random.normal(ks[2], shape)
+    table = (1 + jax.random.permutation(ks[3], n * WALK_T)).reshape(n, WALK_T)
+    table = table.astype(jnp.int32)
+    if not quant:
+        return q, (pk, pv), (pk, pv), table
+    (qk, sk), (qv, sv) = kv_quant(pk), kv_quant(pv)
+    deq = (ref.dequant_pool_ref(qk, sk), ref.dequant_pool_ref(qv, sv))
+    return q, (qk, qv, sk, sv), deq, table
+
+
+def _decode(q, pools, pos, window, table):
+    k, v, *scales = pools
+    kw = dict(zip(("k_scale", "v_scale"), scales))
+    return paged_decode(q, k, v, pos, window, table=table, **kw)
+
+
+class TestTableWalk:
+    def test_shapes_walk_several_blocks(self):
+        assert walks_pages(WALK_PAGE, WALK_HKV, WALK_HD, quant=True)
+        assert 1 < WALK_PPB < WALK_T
+
+    @pytest.mark.parametrize("quant", [False, True], ids=["fp", "int8"])
+    @pytest.mark.parametrize("span", list(SPANS))
+    def test_live_span_bitwise(self, span, quant):
+        """Rows whose live span is one page, exactly a block, a block and
+        one page, and the whole (wrapped) table, between single-page rows
+        so that each row starts on either buffer: bitwise the contiguous
+        kernel at the block's page size (fp) or the fp walk over the
+        dequantized pool (int8), and close to the oracle."""
+        p, window = SPANS[span]
+        q, pools, (dk, dv), table = _walk_pool(
+            jax.random.PRNGKey(len(span)), 3, quant
+        )
+        pos = jnp.asarray([p, 3, p], jnp.int32)
+        out = _decode(q, pools, pos, window, table)
+        cap = WALK_T * WALK_PAGE
+        kc = dk[table].reshape(3, cap, WALK_HKV, WALK_HD)
+        vc = dv[table].reshape(3, cap, WALK_HKV, WALK_HD)
+        expected = paged_decode(
+            q, kc, vc, pos, window, page=WALK_PPB * WALK_PAGE
+        )
+        if quant:
+            np.testing.assert_array_equal(
+                np.asarray(out), np.asarray(_decode(q, (dk, dv), pos, window, table))
+            )
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(expected))
+        oracle = ref.paged_table_decode_ref(q, dk, dv, pos, table, window)
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(oracle), rtol=3e-5, atol=3e-5
+        )
+
+
+def _poison_dead_pages(pools, table, pos, cap):
+    """NaN in every pool page no row has live (the scale planes of an int8
+    pool), and every table entry past a row's live span sent to scratch
+    page 0, which is dead too."""
+    page = pools[0].shape[1]
+    live_pages = np.minimum((np.minimum(np.asarray(pos) + 1, cap) + page - 1) // page,
+                            table.shape[1])
+    tab = np.array(table)
+    for r, n in enumerate(live_pages):
+        tab[r, n:] = 0
+    live = np.zeros(pools[0].shape[0], bool)
+    live[np.concatenate([tab[r, :n] for r, n in enumerate(live_pages)])] = True
+    dead = jnp.asarray(~live)
+    poisoned = [
+        jnp.where(dead.reshape((-1,) + (1,) * (x.ndim - 1)), jnp.nan, x)
+        if jnp.issubdtype(x.dtype, jnp.floating) else x
+        for x in pools
+    ]
+    return poisoned, jnp.asarray(tab)
+
+
+@pytest.mark.parametrize("path", ["walk-fp", "walk-int8", "grid-fp"])
+def test_poisoned_dead_pages_never_read(path):
+    """No dead page reaches the kernel's arithmetic: with NaN in every page
+    that no row has live, the output is bitwise unchanged and finite."""
+    hd = GRID_HD if path == "grid-fp" else WALK_HD
+    quant = path == "walk-int8"
+    key = jax.random.PRNGKey(91)
+    if hd == WALK_HD:
+        q, pools, _, table = _walk_pool(key, 4, quant)
+        page, t_w = WALK_PAGE, WALK_T
+    else:
+        page, t_w = 16, WALK_T
+        q, kc, vc = _rand(key, page * t_w, 4, hkv=WALK_HKV, hd=hd)
+        pk, pv, table = _scatter_to_pool(kc, vc, page, jax.random.fold_in(key, 1))
+        pools = (pk, pv)
+    cap = page * t_w
+    # one page, a block edge and one, most of the table, and a wrapped row
+    pos = jnp.asarray([2, WALK_PPB * page, cap - 20, cap + 37], jnp.int32)
+    window = 0
+    clean = _decode(q, pools, pos, window, table)
+    poisoned, tab = _poison_dead_pages(pools, table, pos, cap)
+    assert bool(jnp.isnan(poisoned[-1]).any())
+    out = _decode(q, poisoned, pos, window, tab)
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(clean))

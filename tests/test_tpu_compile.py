@@ -3,7 +3,8 @@
 Interpret mode (the rest of the suite) accepts block shapes the TPU
 lowering refuses, so these tests compile every serving kernel with
 ``interpret=False`` for one device of a DESCRIBED ``v5e:2x2`` topology, at
-stablelm-1.6b widths (32 kv heads of head_dim 64, bf16, page 16). Nothing
+stablelm-1.6b widths (32 kv heads of head_dim 64, bf16, page 16), and the
+paged decode also at Mistral-NeMo's (8 kv heads of 128). Nothing
 runs: a compile that passes here proves the Mosaic lowering and the
 compiler accept the kernel, and each test checks the kernel is really in
 the program (``tpu_custom_call``) under its stable ``pallas_call`` name,
@@ -25,7 +26,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.configs import get_config
 from repro.kernels.flash_prefill import flash_prefill
 from repro.kernels.flash_suffix_prefill import suffix_prefill
-from repro.kernels.paged_decode import paged_decode
+from repro.kernels.paged_decode import paged_decode, walks_pages
 from repro.kernels.quantize import int8_encode, int8_roundtrip
 from repro.kernels.swa_decode import swa_decode
 
@@ -116,6 +117,39 @@ def test_paged_decode_table_int8(one_chip):
         ),
         q, pool, pool, pos, table, scale, scale,
     )
+    assert "tpu_custom_call" in text
+    assert _kernel_names(text) == {"paged_decode"}
+
+
+# Mistral-NeMo's decode shapes, the benchmark's serving cell: 32 slots,
+# 8 kv heads of 128 (G 4), page 16, a 256-page table over 2,753 pages.
+# A lane-aligned head_dim takes the page walk (grid (B,), page DMAs).
+NEMO_SLOTS, NEMO_HKV, NEMO_G, NEMO_HD = 32, 8, 4, 128
+NEMO_TABLE_W, NEMO_PAGES = 256, 2753
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8], ids=["fp", "int8"])
+def test_paged_decode_table_nemo(one_chip, kv_dtype):
+    quant = kv_dtype == jnp.int8
+    assert walks_pages(PAGE, NEMO_HKV, NEMO_HD, quant)
+    q = _shape(one_chip, (NEMO_SLOTS, NEMO_HKV, NEMO_G, NEMO_HD), jnp.bfloat16)
+    pool = _shape(one_chip, (NEMO_PAGES, PAGE, NEMO_HKV, NEMO_HD), kv_dtype)
+    pos = _shape(one_chip, (NEMO_SLOTS,), jnp.int32)
+    table = _shape(one_chip, (NEMO_SLOTS, NEMO_TABLE_W), jnp.int32)
+    scale = _shape(one_chip, (NEMO_PAGES, PAGE, NEMO_HKV), jnp.float32)
+    fn = functools.partial(paged_decode, interpret=False)
+    if quant:
+        text = _compiled_text(
+            lambda q, k, v, p, t, ks, vs: fn(
+                q, k, v, p, table=t, k_scale=ks, v_scale=vs
+            ),
+            q, pool, pool, pos, table, scale, scale,
+        )
+    else:
+        text = _compiled_text(
+            lambda q, k, v, p, t: fn(q, k, v, p, table=t),
+            q, pool, pool, pos, table,
+        )
     assert "tpu_custom_call" in text
     assert _kernel_names(text) == {"paged_decode"}
 
